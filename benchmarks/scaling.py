@@ -107,6 +107,9 @@ def run(small: bool = True):
     tip_base = None
     for n in devs:
         env = dict(os.environ)
+        # virtual host devices for structure rows: the children stay off
+        # any accelerator (the parent benchmark process may hold it)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
         env["PYTHONPATH"] = os.path.join(ROOT, "src")
         out = subprocess.run(

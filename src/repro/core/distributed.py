@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..sharding.compat import shard_map
+from jax import shard_map
 from . import csr
 from .beindex import BEIndex, build_beindex
 from .graph import BipartiteGraph

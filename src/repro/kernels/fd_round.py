@@ -34,10 +34,9 @@ summed as int32 by the in-kernel scatter-add — exact while W_p < 2²⁴
 (guarded at pack time; the per-edge loss itself is int32 and may exceed
 2²⁴ safely).  Masks travel as int32 0/1 blocks.
 
-The in-kernel gather (``S_pad[e1]``) and scatter-add are interpret-mode
-legal everywhere; on a real TPU backend their Mosaic lowering is the
-compatibility boundary — ``kernels/ops.py`` defaults to interpret mode
-off-TPU like every other kernel here (see docs/KERNELS.md).
+The kernel runs in interpret mode only (CPU), and ``kernels/ops.py``
+refuses it on a TPU backend: it cannot lower to Mosaic (see
+:data:`MOSAIC_LIMITS` and docs/KERNELS.md).
 """
 from __future__ import annotations
 
@@ -45,9 +44,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["fd_round_wing_pallas", "fd_round_tip_pallas"]
+__all__ = ["MOSAIC_LIMITS", "fd_round_wing_pallas", "fd_round_tip_pallas"]
 
 _BIG = jnp.iinfo(jnp.int32).max  # == peelspec._FD_BIG
+
+# Why Mosaic refuses this kernel (compiled for a v5e): the (1, E) /
+# (1, 1) / (1, R) blocks over (B, ...) state break the (8, 128) tiling
+# rule, and with B = 1 (whole-array blocks) lowering stops at the
+# in-kernel gather ``S_pad[e1]`` ("Only 2D gather is supported") and the
+# in-kernel scatter-add.  Lifting this needs a rewrite with neither.
+MOSAIC_LIMITS = (
+    "kernels.fd_round has no Mosaic (TPU) lowering — its (1, E)/(1, 1)/"
+    "(1, R) blocks break the (8, 128) tiling rule and its in-kernel "
+    "gather and scatter-add do not lower (\"Only 2D gather is "
+    "supported\"); it runs in CPU interpret mode only")
 
 
 def _advance(sup, alive, theta, k):

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handle padding to MXU-aligned blocks, interpret-mode selection (CPU
-container → interpret=True; real TPU → compiled), and the bloom-major
-dense packing used by ``bloom_update_pallas``.
+Handle padding to MXU-aligned blocks, interpret-mode selection (off a
+TPU backend → interpret=True; on a TPU backend → compiled Mosaic, and
+interpret mode is refused there), and the bloom-major dense packing
+used by ``bloom_update_pallas``.
 """
 from __future__ import annotations
 
@@ -19,7 +20,7 @@ from .butterfly_count import (
     vertex_count_pallas,
     vertex_count_tile_pallas,
 )
-from .fd_round import fd_round_tip_pallas, fd_round_wing_pallas
+from .fd_round import MOSAIC_LIMITS, fd_round_tip_pallas, fd_round_wing_pallas
 from .flash_attention import flash_attention_pallas
 from .support_update import support_update_pallas
 from .wedge_count import wedge_count_pallas, wedge_count_tile_pallas
@@ -46,6 +47,19 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _interpret(interpret: bool | None) -> bool:
+    """Resolve a wrapper's ``interpret`` argument: ``None`` takes the
+    backend default.  Interpret mode on a TPU backend is refused — it
+    would emulate the kernel on the host and hide the device."""
+    if interpret is None:
+        return default_interpret()
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "Pallas interpret mode requested on a TPU backend; pass "
+            "interpret=None (compiled Mosaic) instead")
+    return interpret
+
+
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
     pad = (-x.shape[axis]) % mult
     if pad == 0:
@@ -57,9 +71,10 @@ def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def vertex_butterflies(
-    A: jax.Array, bm: int = 128, bn: int = 128, interpret: bool = True
+    A: jax.Array, bm: int = 128, bn: int = 128, interpret: bool | None = None
 ) -> jax.Array:
     """Per-row butterfly counts via the fused count kernel."""
+    interpret = _interpret(interpret)
     n = A.shape[0]
     Ap = _pad_to(_pad_to(A.astype(jnp.float32), bm, 0), 128, 1)
     # rows must also tile by bn for the column blocks of W
@@ -106,8 +121,7 @@ def tile_row_counts(
     bucketed (``_row_bucket``) so repeated tiles hit a handful of
     compiled shapes instead of one per tile.
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = _interpret(interpret)
     n = slots.shape[0]
     nb = _row_bucket(n, bp)
     if nb > n:
@@ -142,8 +156,7 @@ def vertex_butterflies_tiled(
     is subtracted here.  Every tile is padded to the same shape — one
     compiled program total.  Returns int64 counts.
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = _interpret(interpret)
     A = np.asarray(A)
     n = A.shape[0]
     deg = A.sum(axis=1).astype(np.int64)
@@ -172,13 +185,14 @@ def edge_wedge_matrix(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """M = (W − 1)·A with W = A·Aᵀ, both matmuls tiled in Pallas.
 
     Uses the identity (W − 1)·A = W·A − d_v so the −1 never materializes.
     Per-edge counts = M[u, v] − (d_u − 1), gathered by the caller.
     """
+    interpret = _interpret(interpret)
     n, nv = A.shape
     Af = A.astype(jnp.float32)
     Ap = _pad_to(_pad_to(Af, max(bm, bn, bk), 0), bk, 1)
@@ -192,13 +206,14 @@ def edge_wedge_matrix(
 
 @functools.partial(jax.jit, static_argnames=("bp", "bk", "interpret"))
 def pair_wedge_counts(
-    slots: jax.Array, bp: int = 128, bk: int = 128, interpret: bool = True
+    slots: jax.Array, bp: int = 128, bk: int = 128, interpret: bool | None = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-pair wedge counts W and the f32 butterfly estimate C(W, 2)
     via the blocked wedge-count kernel (estimate is exact only while
     W(W−1) fits f32 integers — see ``wedge_count.py``).  ``slots`` is
     the pairs-major alive matrix (``core.csr.pack_wedge_slots``);
     padding is handled here."""
+    interpret = _interpret(interpret)
     n = slots.shape[0]
     s = _pad_to(_pad_to(slots.astype(jnp.float32), bp, 0), bk, 1)
     W, bf = wedge_count_pallas(s, bp=bp, bk=bk, interpret=interpret)
@@ -208,7 +223,7 @@ def pair_wedge_counts(
 @functools.partial(jax.jit, static_argnames=("bp", "bk", "interpret"))
 def tip_slot_loss(
     vals: jax.Array, bp: int = 128, bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Per-row f32 sums of masked pair-butterfly values — the tip CD
     support delta through the blocked wedge-count kernel.
@@ -219,6 +234,7 @@ def tip_slot_loss(
     row-sum phase IS the delta (its C(W, 2) output is ignored).  Rows
     are vertices, so the result needs no scatter.  Exact while per-row
     sums stay under 2²⁴ (guarded at pack time)."""
+    interpret = _interpret(interpret)
     n = vals.shape[0]
     v = _pad_to(_pad_to(vals.astype(jnp.float32), bp, 0), bk, 1)
     W, _ = wedge_count_pallas(v, bp=bp, bk=bk, interpret=interpret)
@@ -233,7 +249,7 @@ def support_update(
     W: jax.Array,
     bp: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One csr support-update round through the blocked Pallas kernel.
 
@@ -245,6 +261,7 @@ def support_update(
     grid).  Padding to (bp, bk) tiles is handled here.  Returns
     (contrib1, contrib2, c) trimmed back to the input shape — per-slot
     losses for each slot's two edges plus dying wedges per row."""
+    interpret = _interpret(interpret)
     n, kdim = pe1.shape
 
     def padf(x):
@@ -256,6 +273,13 @@ def support_update(
         bp=bp, bk=bk, interpret=interpret,
     )
     return c1[:n, :kdim], c2[:n, :kdim], c[:n]
+
+
+def _require_interpret(interpret: bool | None) -> None:
+    """The fused FD round runs in interpret mode or not at all."""
+    if not _interpret(interpret):
+        raise NotImplementedError(
+            f"the fused FD round kernel cannot run compiled: {MOSAIC_LIMITS}")
 
 
 # The fd_round wrappers are deliberately NOT jitted: they only ever run
@@ -271,12 +295,12 @@ def fd_round_wing(sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2,
     State in/out (same order): sup/alive/theta (B, E) i32, k/rounds/
     nupd (B, 1) i32, wedge-slot alive (B, R, K) i32, W (B, R) f32.
     ``e1``/``e2`` are the static (B, R, K) local edge ids with sentinel
-    E (``distributed._pack_fd_slots_csr``)."""
-    if interpret is None:
-        interpret = default_interpret()
+    E (``distributed._pack_fd_slots_csr``).  Interpret mode only: the
+    kernel has no Mosaic lowering (``fd_round.MOSAIC_LIMITS``)."""
+    _require_interpret(interpret)
     return fd_round_wing_pallas(
         sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2,
-        interpret=interpret)
+        interpret=True)
 
 
 def fd_round_tip(sup, alive, theta, k, rounds, pa, pb, bf,
@@ -286,11 +310,11 @@ def fd_round_tip(sup, alive, theta, k, rounds, pa, pb, bf,
     State in/out (same order): sup/alive/theta (B, E) i32, k/rounds
     (B, 1) i32.  ``pa``/``pb``/``bf`` are the static (B, L) partition-
     local pair lists (``pack_fd_partitions_tip_csr(stacked=True)``;
-    bf=0 padding is algebra-neutral)."""
-    if interpret is None:
-        interpret = default_interpret()
+    bf=0 padding is algebra-neutral).  Interpret mode only, like
+    :func:`fd_round_wing`."""
+    _require_interpret(interpret)
     return fd_round_tip_pallas(
-        sup, alive, theta, k, rounds, pa, pb, bf, interpret=interpret)
+        sup, alive, theta, k, rounds, pa, pb, bf, interpret=True)
 
 
 def pack_blooms(
@@ -338,11 +362,12 @@ def bloom_update(
     canon: jax.Array,        # [nb_pad, K] bool
     m: int = 0,
     bb: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One batched support-update round through the Pallas kernel.
 
     Returns (loss per edge (m,), c per bloom, new alive_pair)."""
+    interpret = _interpret(interpret)
     sent = peeled.shape[0] - 1
     lei = jnp.where(le < 0, sent, le)
     lti = jnp.where(lt < 0, sent, lt)
@@ -368,8 +393,9 @@ def flash_attention(
     causal: bool = True,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
+    interpret = _interpret(interpret)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bq = min(bq, sq) if sq % min(bq, sq) == 0 else bq

@@ -67,13 +67,13 @@ def _support_update_kernel(
 
     @pl.when(phase == 0)
     def _accumulate():
-        acc_ref[...] += jnp.sum(dies, axis=1)
+        acc_ref[...] += jnp.sum(dies, axis=1, keepdims=True)
 
     @pl.when(phase == 1)
     def _emit():
-        c = acc_ref[...]
-        surv_loss = (alive - dies) * c[:, None]          # survivor rule
-        widow = dies * (w_ref[...] - 1.0)[:, None]       # widow rule
+        c = acc_ref[...]                                 # (bp, 1)
+        surv_loss = (alive - dies) * c                   # survivor rule
+        widow = dies * (w_ref[...] - 1.0)                # widow rule
         c1_ref[...] = (1.0 - pe1_ref[...]) * widow + surv_loss
         c2_ref[...] = (1.0 - pe2_ref[...]) * widow + surv_loss
         c_ref[...] = c
@@ -100,25 +100,20 @@ def support_update_pallas(
     assert n % bp == 0 and kdim % bk == 0, "pad slots before calling"
     grid = (n // bp, 2, kdim // bk)
     slot_spec = pl.BlockSpec((bp, bk), lambda i, ph, k: (i, k))
-    return pl.pallas_call(
+    # per-row vectors travel as (n, 1) columns: Mosaic refuses rank-1
+    # (bp,) blocks (see kernels.wedge_count)
+    row_spec = pl.BlockSpec((bp, 1), lambda i, ph, k: (i, 0))
+    c1, c2, c = pl.pallas_call(
         _support_update_kernel,
         grid=grid,
-        in_specs=[
-            slot_spec,
-            slot_spec,
-            slot_spec,
-            pl.BlockSpec((bp,), lambda i, ph, k: (i,)),
-        ],
-        out_specs=[
-            slot_spec,
-            slot_spec,
-            pl.BlockSpec((bp,), lambda i, ph, k: (i,)),
-        ],
+        in_specs=[slot_spec, slot_spec, slot_spec, row_spec],
+        out_specs=[slot_spec, slot_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((n, kdim), jnp.float32),
             jax.ShapeDtypeStruct((n, kdim), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bp,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bp, 1), jnp.float32)],
         interpret=interpret,
-    )(pe1, pe2, alive, W)
+    )(pe1, pe2, alive, W.reshape(n, 1))
+    return c1, c2, c[:, 0]

@@ -15,6 +15,12 @@ suitable for CD range *estimation*, never for final θ (the engine's
 exact path derives counts from the int32 W instead and discards this
 output).  Block shapes are TPU-tile aligned (sublane 8 × lane 128 for
 f32); ``interpret=True`` runs the same kernel on CPU for CI.
+
+Per-row outputs are (n, 1) columns written in (bp, 1) blocks: Mosaic
+refuses rank-1 output blocks whose XLA layout tiles differently
+(``{0:T(1024)}`` vs its ``{0:T(128)}``), while a block whose last dim
+equals the array's passes the (8, 128) tiling rule for any bp % 8 == 0.
+The wrappers below return the flat (n,) vectors.
 """
 from __future__ import annotations
 
@@ -33,7 +39,7 @@ def _wedge_count_kernel(slots_ref, w_ref, bf_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.sum(slots_ref[...], axis=1)
+    acc_ref[...] += jnp.sum(slots_ref[...], axis=1, keepdims=True)
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _done():
@@ -49,7 +55,7 @@ def _wedge_count_tile_kernel(slots_ref, w_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.sum(slots_ref[...], axis=1)
+    acc_ref[...] += jnp.sum(slots_ref[...], axis=1, keepdims=True)
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _done():
@@ -67,7 +73,7 @@ def wedge_count_tile_pallas(
     int32 partials the host reduces in int64 — no f32 round-trip, no
     C(W, 2) emit, and therefore none of the 2²⁴ exactness ceiling of
     :func:`wedge_count_pallas`.  Per-launch device working set is one
-    (bp, bk) block + the (bp,) accumulator regardless of tile size.
+    (bp, bk) block + the (bp, 1) accumulator regardless of tile size.
 
     slots: (n_rows_pad, width) int32 0/1 flags, pre-padded to (bp, bk)
     multiples.  Returns (n_rows_pad,) int32 row sums.
@@ -75,15 +81,16 @@ def wedge_count_tile_pallas(
     n, kdim = slots.shape
     assert n % bp == 0 and kdim % bk == 0, "pad slots before calling"
     grid = (n // bp, kdim // bk)
-    return pl.pallas_call(
+    w = pl.pallas_call(
         _wedge_count_tile_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bp, bk), lambda i, k: (i, k))],
-        out_specs=pl.BlockSpec((bp,), lambda i, k: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bp,), jnp.int32)],
+        out_specs=pl.BlockSpec((bp, 1), lambda i, k: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bp, 1), jnp.int32)],
         interpret=interpret,
     )(slots)
+    return w[:, 0]
 
 
 def wedge_count_pallas(
@@ -98,18 +105,17 @@ def wedge_count_pallas(
     n, kdim = slots.shape
     assert n % bp == 0 and kdim % bk == 0, "pad slots before calling"
     grid = (n // bp, kdim // bk)
-    return pl.pallas_call(
+    col = pl.BlockSpec((bp, 1), lambda i, k: (i, 0))
+    w, bf = pl.pallas_call(
         _wedge_count_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bp, bk), lambda i, k: (i, k))],
-        out_specs=[
-            pl.BlockSpec((bp,), lambda i, k: (i,)),
-            pl.BlockSpec((bp,), lambda i, k: (i,)),
-        ],
+        out_specs=[col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bp,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bp, 1), jnp.float32)],
         interpret=interpret,
     )(slots)
+    return w[:, 0], bf[:, 0]

@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import ARCHS, get_config  # noqa: E402
 from repro.launch.hlo_analysis import collective_bytes  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.sharding.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 import repro.models as M  # noqa: E402
 from repro.models.model import SHAPE_SETS  # noqa: E402
 from repro.sharding import (  # noqa: E402

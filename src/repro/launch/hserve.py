@@ -80,6 +80,8 @@ def _dryrun() -> int:
     import tempfile
 
     import jax
+    # virtual host devices: pin the CPU platform (see launch/peel.py)
+    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from repro.core.graph import powerlaw_bipartite
@@ -250,6 +252,8 @@ def main():
                          "(one compile per bucket, zero-retrace cold "
                          "load, loop/collective-free dispatch)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro import obs
         obs.enable()

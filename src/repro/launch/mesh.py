@@ -7,8 +7,7 @@ tests and benches must keep seeing a single device).
 from __future__ import annotations
 
 import jax
-
-from repro.sharding.compat import HAS_AXIS_TYPE, AxisType
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
@@ -20,8 +19,6 @@ __all__ = [
 
 def _mesh(shape, axes):
     # GSPMD auto-propagation semantics (explicit-mode is jax>=0.9 default)
-    if not HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -26,7 +26,7 @@ class LaunchError(SystemExit):
         super().__init__(f"[peel] error: {msg}")
 
 
-def _validate(args, n_dev: int) -> None:
+def _validate(args, n_dev: int, platform: str) -> None:
     """Resolve the per-kind engine default, then reject unsupported
     flag combinations with explicit errors."""
     if args.engine is None:
@@ -63,6 +63,11 @@ def _validate(args, n_dev: int) -> None:
             "--aligned is the one-psum CD sharding (csr: pair/vertex "
             "aligned; beindex: bloom aligned); --engine dense has no "
             "sharded index to align")
+    if args.fused_fd and platform == "tpu":
+        from repro.kernels.fd_round import MOSAIC_LIMITS
+        raise LaunchError(
+            f"--fused-fd on a TPU backend: {MOSAIC_LIMITS}.  The default "
+            "(unfused) device FD path runs on the chip")
     if args.fused_fd and args.engine != "csr":
         raise LaunchError(
             "--fused-fd is the fused csr FD round kernel; pass "
@@ -101,14 +106,6 @@ def _validate(args, n_dev: int) -> None:
             raise LaunchError(
                 "--aligned shards the CD index across devices; it needs "
                 "a multi-device mesh (or use --dryrun)")
-    if args.fused_fd is None:
-        # default ON where supported: single device, csr engine, a
-        # device-side FD driver — the zero-per-round-dispatch round is
-        # θ-bit-identical to the unfused path, so there is no reason
-        # not to take it (pass --no-fused-fd for the A/B baseline)
-        args.fused_fd = (
-            n_dev == 1 and args.engine == "csr"
-            and args.fd_driver in ("device", "vmapped"))
 
 
 def _dryrun() -> int:
@@ -117,8 +114,12 @@ def _dryrun() -> int:
         "--xla_force_host_platform_device_count=512 "
         + os.environ.get("XLA_FLAGS", ""))
     import jax
+    # the 512 devices are virtual host devices: pin the CPU platform so a
+    # machine with an accelerator still builds the host mesh
+    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import distributed as D
@@ -126,7 +127,6 @@ def _dryrun() -> int:
     from repro.core.graph import powerlaw_bipartite
     from repro.core.peel import wing_decomposition
     from repro.launch.mesh import make_peel_mesh
-    from repro.sharding.compat import shard_map
 
     mesh = make_peel_mesh(512)
     g = powerlaw_bipartite(400, 200, 2000, seed=1)
@@ -175,8 +175,6 @@ def _dryrun() -> int:
     print("[peel-dryrun] FD peel compiled at 512 devices; "
           "NO collectives in HLO ✓")
     ca = fd_comp.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
     print(f"[peel-dryrun] FD flops/device={ca.get('flops', -1):.3e} "
           f"bytes={ca.get('bytes accessed', -1):.3e}")
 
@@ -392,7 +390,10 @@ def _emit_hierarchy(args, g, result, kind: str, stats=None) -> None:
               f"at k={top['level'].tolist()}")
 
 
-def _run(args) -> int:
+def run(args) -> dict:
+    """Peel the graph ``args`` names; returns ``dict(graph, theta,
+    result, stats)`` (``result`` is the PeelResult).  ``main`` is the
+    command-line wrapper; ``chip_smoke.py`` calls this in-process."""
     import jax
     import numpy as np
 
@@ -402,7 +403,7 @@ def _run(args) -> int:
     from repro.launch.mesh import make_peel_mesh
 
     n_dev = len(jax.devices())
-    _validate(args, n_dev)
+    _validate(args, n_dev, jax.default_backend())
 
     sup0 = None
     if args.edges:
@@ -435,7 +436,8 @@ def _run(args) -> int:
     elif args.dataset:
         g = paper_proxy_dataset(args.dataset)
     else:
-        g = powerlaw_bipartite(args.n_u, args.n_v, args.m, seed=args.seed)
+        g = powerlaw_bipartite(args.n_u, args.n_v, args.m, alpha=args.alpha,
+                               seed=args.seed)
     print(f"[peel] graph |U|={g.n_u} |V|={g.n_v} |E|={g.m}")
 
     stats_out = {}
@@ -498,10 +500,11 @@ def _run(args) -> int:
     if args.out:
         with open(args.out, "w") as f:
             json.dump(dict(theta=theta.tolist(), stats=stats_out), f)
-    return 0
+    return dict(graph=g, theta=theta, result=result, stats=stats_out)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (shared with ``chip_smoke.py``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--kind", "--mode", dest="kind",
                     choices=["wing", "tip"], default="wing",
@@ -529,6 +532,9 @@ def main():
     ap.add_argument("--n-u", type=int, default=400)
     ap.add_argument("--n-v", type=int, default=200)
     ap.add_argument("--m", type=int, default=2000)
+    ap.add_argument("--alpha", type=float, default=1.3,
+                    help="degree-skew exponent of the generated power-law "
+                         "graph (smaller = flatter degrees, more wedges)")
     ap.add_argument("--parts", type=int, default=16)
     ap.add_argument("--engine", default=None,
                     choices=["beindex", "dense", "csr"],
@@ -549,14 +555,13 @@ def main():
                          "pair-aligned wedges; tip csr: vertex-aligned "
                          "pair entries; wing beindex: bloom-aligned "
                          "links)")
-    ap.add_argument("--fused-fd", action=argparse.BooleanOptionalAction,
-                    default=None,
+    ap.add_argument("--fused-fd", action="store_true",
                     help="csr engines, single device: run every FD round "
                          "as ONE fused Pallas launch (kernels.fd_round) "
                          "— k-advance + compaction + support update "
-                         "in-kernel, zero per-round dispatch tail.  "
-                         "Default: on where supported; --no-fused-fd "
-                         "forces the unfused A/B baseline")
+                         "in-kernel.  CPU interpret mode only: the kernel "
+                         "has no Mosaic lowering, so a TPU backend "
+                         "refuses it")
     ap.add_argument("--use-pallas", action="store_true",
                     help="csr engines only: run CD support updates "
                          "through the blocked Pallas kernels (and, for "
@@ -578,11 +583,21 @@ def main():
                          "spans, per-round cd.round/fd.round events, "
                          "hierarchy build spans.  Off by default — the "
                          "traced programs are byte-identical without it")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro import obs
         obs.enable()
-    rc = _dryrun() if args.dryrun else _run(args)
+    if args.dryrun:
+        rc = _dryrun()
+    else:
+        run(args)
+        rc = 0
     if args.trace:
         tracer = obs.get_tracer()
         tracer.save(args.trace)

@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch.mesh import make_local_mesh
-from repro.sharding.compat import set_mesh
+from jax import set_mesh
 import repro.models as M
 from repro.models.config import reduced
 

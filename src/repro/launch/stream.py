@@ -246,6 +246,8 @@ def main():
                     help="small-graph self-check: per-epoch bit-"
                          "identity vs from-scratch re-peel, both kinds")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro import obs
         obs.enable()

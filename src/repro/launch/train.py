@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.data import DataConfig, synthetic_batches
 from repro.launch.mesh import make_local_mesh
-from repro.sharding.compat import set_mesh
+from jax import set_mesh
 import repro.models as M
 from repro.models.config import reduced
 from repro.sharding import batch_shardings, param_shardings

@@ -1,4 +1,4 @@
-from .compat import shard_map
+from jax import shard_map
 from .partition import (
     LOGICAL_RULES,
     batch_shardings,

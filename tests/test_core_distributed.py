@@ -86,7 +86,7 @@ def test_fd_hlo_has_no_collectives():
             return jnp.asarray(np.concatenate([x, fill], 0))
         args = tuple(padp(packed[k]) for k in
                      ("le","lt","lb","alive0","canon","k0","sup0","mine"))
-        from repro.sharding.compat import shard_map
+        from jax import shard_map
         vb = jax.vmap(D._fd_body_one_partition)
         fn = shard_map(vb, mesh=mesh,
                        in_specs=tuple(P("peel") for _ in args),
@@ -164,7 +164,7 @@ def test_csr_fd_hlo_has_no_collectives():
         from repro.core import csr
         from repro.core.peel import wing_decomposition
         from repro.core import distributed as D
-        from repro.sharding.compat import shard_map
+        from jax import shard_map
         g = random_bipartite(20, 16, 64, seed=3)
         wed = csr.build_wedges(g)
         res = wing_decomposition(g, P=4, engine="csr")
@@ -495,7 +495,7 @@ def test_tip_csr_fd_hlo_has_no_collectives():
         from repro.core import csr
         from repro.core.peel import tip_decomposition
         from repro.core import distributed as D
-        from repro.sharding.compat import shard_map
+        from jax import shard_map
         g = random_bipartite(20, 16, 64, seed=3)
         wed = csr.build_wedges(g)
         bf0 = wed.pair_butterflies0()

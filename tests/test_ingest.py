@@ -94,7 +94,7 @@ _OPS = st.lists(
 
 
 @settings(max_examples=60, deadline=None)
-@given(_OPS, st.randoms(use_true_random=False))
+@given(raw_ops=_OPS, rng=st.randoms(use_true_random=False))
 def test_ingest_invariant_to_chunks_and_order(raw_ops, rng, tmp_path_factory):
     tmp = tmp_path_factory.mktemp("ing")
     ops = [(*_raw(u, v), 1 if ins else -1) for ins, u, v in raw_ops]

@@ -126,17 +126,13 @@ def test_crash_and_resume(tmp_path):
 
 
 def test_elastic_remesh_subprocess():
-    """Restore state onto a different device count (pod loss): 8 -> 4.
-
-    Imports ``AxisType`` through ``repro.sharding.compat`` (the pinned
-    jax<0.5 has no ``jax.sharding.AxisType``; the shim provides the
-    sentinel enum there and the real one on newer jax)."""
+    """Restore state onto a different device count (pod loss): 8 -> 4."""
     import textwrap
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.sharding.compat import AxisType
+        from jax.sharding import AxisType
         assert hasattr(AxisType, "Auto")
         import repro.models as M
         from repro.configs import get_config
