@@ -11,24 +11,27 @@ Phases, all in this one process (the chip belongs to one process):
     the BUP oracle (``core/ref.py``); the real ``southern_women`` edge
     list through the tiled ⋈init with ``--use-pallas``, θ against
     ``tests/goldens/real_graphs.json``.  Also asserts that no Pallas
-    kernel runs in interpret mode, that the ``--use-pallas`` programs
-    compile to Mosaic (``tpu_custom_call``), and that ``--fused-fd`` is
+    kernel runs in interpret mode, that every jitted Pallas entry point
+    those runs called compiles, at the shapes it was called with, to a
+    Mosaic kernel (``tpu_custom_call``), and that ``--fused-fd`` is
     refused.
 (b) full size — ``--kind wing|tip --engine csr`` with the launcher's
     default flags on the seeded skewed ``peel`` graph of
     ``tests/goldens/record_chip_smoke.py``; prints |E|, the wedge count,
     device bytes, compile and run seconds, and checks each θ sha256
     against the CPU golden (``tests/goldens/chip_smoke.json``).
-(c) forest and queries — on the smaller ``forest`` graph (the largest
-    whose wing forest builds in seconds): peel, ``build_hierarchy`` →
-    ``pack_forest`` → 4096 mixed ``HQuery`` through ``HierarchyService``
-    for wing and tip; θ and the forest digest against the CPU golden,
-    every answer against a host oracle on that forest.
+(c) forest and queries — on the smaller ``forest`` graph (at phase
+    (b)'s size the wing build does not fit the time (a) and (b) leave;
+    see the recorder): peel, ``build_hierarchy`` → ``pack_forest`` →
+    4096 mixed ``HQuery`` through ``HierarchyService`` for wing and tip;
+    θ and the forest digest against the CPU golden, every answer
+    against a host oracle on that forest.
 
 ``--chips 4`` runs only the distributed csr paths (``--aligned``, wing
 and tip) on a 4-chip mesh and the single-chip peel of the same
 (``forest``) graph on one of those chips; θ must be bit-identical (and
-equal the CPU golden).
+equal the CPU golden), and every sharded CD input the distributed run
+placed must have a shard on each of the 4 devices.
 
 Usage, from the repo root on a machine with a TPU::
 
@@ -42,6 +45,8 @@ when every phase passed.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import importlib
 import json
 import os
 import sys
@@ -63,6 +68,14 @@ from record_chip_smoke import (KINDS, forest_digest,  # noqa: E402
 # csr FD paths the launcher can take on one chip (--fused-fd is refused)
 FD_PATHS = [("device", False), ("device", True),
             ("vmapped", False), ("vmapped", True)]
+# the jitted entry points through which --use-pallas reaches a kernel
+PALLAS_ENTRIES = {
+    "wing CD support_update": ("repro.kernels.ops", "support_update"),
+    "tip CD tip_slot_loss": ("repro.kernels.ops", "tip_slot_loss"),
+    "tiled init tile_row_counts": ("repro.kernels.ops",
+                                   "_tile_row_counts_inner"),
+    "wing vmapped FD": ("repro.core.peel", "_fd_wing_vmapped_pallas"),
+}
 N_QUERIES = 4096
 
 _COMPILE = dict(s=0.0, hits=0)
@@ -107,42 +120,39 @@ def _golden() -> dict:
 
 
 # ---------------------------------------------------------------- (a)
-def _pallas_programs(g) -> dict:
-    """Compiled HLO of the programs ``--use-pallas`` runs, at ``g``'s
-    slot shapes: wing CD, tip CD, wing vmapped FD, tiled ⋈init."""
-    import jax.numpy as jnp
+class _Recorder:
+    """Stands in for a jitted entry point: runs it unchanged and keeps
+    the abstract signature (shapes, dtypes, static arguments) of every
+    call made outside a trace, so the program each call ran can be
+    compiled again and read."""
 
-    from repro.core import csr
-    from repro.core.distributed import pack_fd_partitions_csr
-    from repro.core.peel import _fd_wing_vmapped_pallas, wing_decomposition
-    from repro.kernels import ops as kops
+    def __init__(self, fn):
+        self.fn, self.calls = fn, {}
 
-    wed = csr.build_wedges(g)
-    slots = csr.pack_update_slots(wed)
-    rows = slots["e1"].shape[0]
-    pe = jnp.zeros(slots["e1"].shape, bool)
-    tip = csr.pack_tip_slots(wed, wed.pair_butterflies0())
-    res = wing_decomposition(g, engine="csr")
-    n_parts = int(res.part.max()) + 1
-    p = pack_fd_partitions_csr(wed, res.part, res.support_init, n_parts,
-                               bucket=True, slots=True)
-    R, _ = p["slot_sizes"]
-    fd_args = [jnp.asarray(p[k]) for k in
-               ("slot_e1", "slot_e2", "slot_valid")]
-    fd_args += [jnp.zeros((n_parts, R), jnp.int32),
-                jnp.asarray(p["mine"]), jnp.asarray(p["sup0"])]
-    lowered = {
-        "wing CD support_update": kops.support_update.lower(
-            pe, pe, jnp.asarray(slots["valid"]),
-            jnp.zeros((rows,), jnp.int32)),
-        "tip CD tip_slot_loss": kops.tip_slot_loss.lower(
-            jnp.asarray(tip["bf"])),
-        "wing vmapped FD": _fd_wing_vmapped_pallas.lower(
-            *fd_args, interpret=False),
-        "tiled init tile_row_counts": kops._tile_row_counts_inner.lower(
-            jnp.zeros((64, 512), jnp.int32), 8, 128, False),
-    }
-    return {k: v.compile().as_text() for k, v in lowered.items()}
+    def __call__(self, *args, **kw):
+        import jax
+
+        if not any(isinstance(x, jax.core.Tracer)
+                   for x in jax.tree.leaves((args, kw))):
+            sig = jax.tree.map(
+                lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype)
+                           if hasattr(x, "shape") else x), (args, kw))
+            self.calls.setdefault(repr(sig), sig)
+        return self.fn(*args, **kw)
+
+
+@contextlib.contextmanager
+def _recording_pallas():
+    recs = {}
+    for what, (mod, attr) in PALLAS_ENTRIES.items():
+        m = importlib.import_module(mod)
+        recs[what] = (m, attr, _Recorder(getattr(m, attr)))
+        setattr(m, attr, recs[what][2])
+    try:
+        yield {what: r for what, (_, _, r) in recs.items()}
+    finally:
+        for m, attr, r in recs.values():
+            setattr(m, attr, r.fn)
 
 
 def phase_a() -> None:
@@ -155,18 +165,33 @@ def phase_a() -> None:
           "Pallas kernels compile for the chip (interpret mode off)")
     g = paper_proxy_dataset("fr")
     oracle = dict(wing=ref.bup_wing_ref(g), tip=ref.bup_tip_ref(g, "u"))
-    for kind in KINDS:
-        for driver, pallas in FD_PATHS:
-            argv = ["--kind", kind, "--engine", "csr", "--dataset", "fr",
-                    "--side", "u", "--fd-driver", driver]
-            argv += ["--use-pallas"] if pallas else []
-            r = _launch(argv)
-            check(np.array_equal(r["theta"], oracle[kind]),
-                  f"fr {kind} fd-driver={driver} use-pallas={pallas}: "
-                  f"θ == BUP oracle")
-    for name, txt in _pallas_programs(g).items():
-        check("tpu_custom_call" in txt,
-              f"{name} program holds a Mosaic kernel (tpu_custom_call)")
+    with open(os.path.join(GOLDENS, "real_graphs.json")) as f:
+        sw = json.load(f)["southern_women"]
+    key = dict(wing="theta_wing_sha256", tip="theta_tip_u_sha256")
+    with _recording_pallas() as recs:
+        for kind in KINDS:
+            for driver, pallas in FD_PATHS:
+                argv = ["--kind", kind, "--engine", "csr", "--dataset", "fr",
+                        "--side", "u", "--fd-driver", driver]
+                argv += ["--use-pallas"] if pallas else []
+                r = _launch(argv)
+                check(np.array_equal(r["theta"], oracle[kind]),
+                      f"fr {kind} fd-driver={driver} use-pallas={pallas}: "
+                      f"θ == BUP oracle")
+        for kind in KINDS:
+            r = _launch(["--kind", kind, "--engine", "csr", "--side", "u",
+                         "--edges", SOUTHERN_WOMEN, "--use-pallas"])
+            check(theta_sha(r["theta"]) == sw[key[kind]],
+                  f"southern_women {kind} (tiled init, use-pallas): θ "
+                  f"sha256 == golden")
+    for what, rec in recs.items():
+        check(len(rec.calls) > 0, f"--use-pallas called the {what}")
+        for args, kw in rec.calls.values():
+            shapes = [tuple(x.shape) for x in args if hasattr(x, "shape")]
+            txt = rec.fn.lower(*args, **kw).compile().as_text()
+            check("tpu_custom_call" in txt,
+                  f"{what} at {shapes}: the program it ran holds a Mosaic "
+                  f"kernel (tpu_custom_call)")
     try:
         _launch(["--kind", "wing", "--engine", "csr", "--dataset", "fr",
                  "--fused-fd"])
@@ -175,16 +200,6 @@ def phase_a() -> None:
         refused = str(e)
     check(refused is not None and "Mosaic" in refused,
           f"--fused-fd refused on the chip: {refused}")
-
-    with open(os.path.join(GOLDENS, "real_graphs.json")) as f:
-        sw = json.load(f)["southern_women"]
-    key = dict(wing="theta_wing_sha256", tip="theta_tip_u_sha256")
-    for kind in KINDS:
-        r = _launch(["--kind", kind, "--engine", "csr", "--side", "u",
-                     "--edges", SOUTHERN_WOMEN, "--use-pallas"])
-        check(theta_sha(r["theta"]) == sw[key[kind]],
-              f"southern_women {kind} (tiled init, use-pallas): θ sha256 "
-              f"== golden")
 
 
 # ---------------------------------------------------------------- (b)
@@ -290,46 +305,6 @@ def phase_c(golden: dict) -> None:
 
 
 # ------------------------------------------------------------ 4 chips
-def _print_shards(g, n_dev: int) -> None:
-    """Place the aligned CD rounds' inputs as their compiled programs
-    ask and print the devices each input's shards land on."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import csr
-    from repro.core import distributed as D
-    from repro.launch.mesh import make_peel_mesh
-
-    mesh = make_peel_mesh()
-    wed = csr.build_wedges(g)
-    pal = D.shard_wedges_pair_aligned(wed, n_dev)
-    tal = D.shard_tip_pairs(wed, wed.pair_butterflies0(), n_dev,
-                            aligned=True)
-    programs = dict(
-        wing=(D.make_cd_round_csr_pair_aligned(mesh, "peel", pal["Pmax"],
-                                               g.m),
-              dict(peeled=jnp.zeros((g.m + 1,), bool),
-                   alive=pal["alive"], W0=pal["W0"],
-                   support=jnp.zeros((g.m + 1,), jnp.int32),
-                   we1=pal["we1"], we2=pal["we2"], wp=pal["wp"])),
-        tip=(D.make_cd_round_tip_csr(mesh, "peel", g.n_u),
-             dict(peeled=jnp.zeros((g.n_u + 1,), bool),
-                  support=jnp.zeros((g.n_u + 1,), jnp.int32),
-                  dst=tal["dst"], src=tal["src"], bf=tal["bf"])),
-    )
-    for kind, (fn, args) in programs.items():
-        vals = [jnp.asarray(v) for v in args.values()]
-        shardings = fn.lower(*vals).compile().input_shardings[0]
-        for name, v, s in zip(args, vals, shardings):
-            x = jax.device_put(v, s)
-            ids = sorted(sh.device.id for sh in x.addressable_shards)
-            shape = x.addressable_shards[0].data.shape
-            print(f"[smoke] 4chip {kind} CD input {name}{tuple(v.shape)}: "
-                  f"shards {shape} on devices {ids}")
-        check(len({sh.device.id for sh in x.addressable_shards}) == n_dev,
-              f"4chip {kind} CD wedge shards span {n_dev} devices")
-
-
 def phase_4chip(golden: dict) -> None:
     import jax
 
@@ -344,6 +319,13 @@ def phase_4chip(golden: dict) -> None:
               f"{time.perf_counter() - t0:.3f} s", flush=True)
         check(r["stats"]["n_dev"] == n_dev,
               f"4chip {kind} ran on the {n_dev}-device mesh")
+        for name, where in r["stats"]["cd_shards"].items():
+            print(f"[smoke] 4chip {kind} CD input {name}: shards "
+                  f"{tuple(where['shard_shape'])} on devices "
+                  f"{where['devices']}")
+            check(len(set(where["devices"])) == n_dev,
+                  f"4chip {kind} CD input {name} has a shard on each of "
+                  f"the {n_dev} devices")
         g = r["graph"]
         t0 = time.perf_counter()
         with jax.default_device(jax.devices()[0]):
@@ -355,7 +337,6 @@ def phase_4chip(golden: dict) -> None:
               f"4chip {kind} θ bit-identical to the single-chip peel")
         check(theta_sha(r["theta"]) == golden[kind]["theta_sha256"],
               f"4chip {kind} θ sha256 == CPU golden")
-    _print_shards(g, n_dev)
 
 
 # ---------------------------------------------------------------- main

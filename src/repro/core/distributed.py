@@ -31,7 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 from . import csr
@@ -1083,6 +1083,21 @@ def fd_peel_sharded_tip_csr(packed: dict, mesh: Mesh, axis: str | Tuple[str, ...
 # =====================================================================
 # End-to-end distributed wing decomposition
 # =====================================================================
+def _place_sharded(mesh: Mesh, axis: str | Tuple[str, ...], **arrays):
+    """Put each CD input on the mesh once, split along its leading axis
+    the way the round's ``P(axis)`` in_spec reads it.  A host or
+    single-device array would instead be re-sent to every shard on each
+    round.  Returns the placed arrays and, per name, the device ids and
+    shape of its addressable shards (``cd_shards`` in the stats)."""
+    sharding = NamedSharding(mesh, P(axis))
+    placed = {k: jax.device_put(v, sharding) for k, v in arrays.items()}
+    where = {
+        k: dict(devices=[int(s.device.id) for s in v.addressable_shards],
+                shard_shape=list(v.addressable_shards[0].data.shape))
+        for k, v in placed.items()}
+    return placed, where
+
+
 def _scatter_theta(theta, packed, theta_loc, n_parts):
     """Map packed-local θ back to global entity ids."""
     for i in range(n_parts):
@@ -1280,11 +1295,11 @@ def _distributed_wing_csr(
         packed = shard_wedges_pair_aligned(wed, n_dev)
         round_fn = make_cd_round_csr_pair_aligned(
             mesh, axis, packed["Pmax"], m)
-        pa_alive = jnp.asarray(packed["alive"])
-        pa_W = jnp.asarray(packed["W0"])
-        pa_we1 = jnp.asarray(packed["we1"])
-        pa_we2 = jnp.asarray(packed["we2"])
-        pa_wp = jnp.asarray(packed["wp"])
+        placed, cd_shards = _place_sharded(
+            mesh, axis, **{k: packed[k]
+                           for k in ("alive", "W0", "we1", "we2", "wp")})
+        pa_alive, pa_W = placed["alive"], placed["W0"]
+        pa_we1, pa_we2, pa_wp = placed["we1"], placed["we2"], placed["wp"]
         sup0 = csr.edge_butterflies0(wed)
         if sup0.size and int(sup0.max()) > 2 ** 31 - 1:
             raise OverflowError(
@@ -1293,6 +1308,9 @@ def _distributed_wing_csr(
         st = None
     else:
         st = shard_wedges(wed, n_dev)
+        placed, cd_shards = _place_sharded(
+            mesh, axis, we1=st.we1, we2=st.we2, wp=st.wp, alive_w=st.alive_w)
+        st = dataclasses.replace(st, **placed)
         round_fn = make_cd_round_csr(mesh, axis, st.n_pairs, m)
         support = st.support
 
@@ -1337,7 +1355,7 @@ def _distributed_wing_csr(
         theta, part, ranges, sup_init, stats,
         dict(cd_sharding="pair_aligned" if pair_aligned else "wedge",
              n_parts=n_parts, n_wedges=wed.n_wedges,
-             n_pairs=wed.n_pairs, n_dev=n_dev),
+             n_pairs=wed.n_pairs, n_dev=n_dev, cd_shards=cd_shards),
         return_result)
 
 
@@ -1472,9 +1490,9 @@ def _distributed_tip_csr(
 
     blocks = shard_tip_pairs(wed, pair_bf0, n_dev, aligned=aligned)
     round_fn = make_cd_round_tip_csr(mesh, axis, n)
-    dst = jnp.asarray(blocks["dst"])
-    src = jnp.asarray(blocks["src"])
-    bf = jnp.asarray(blocks["bf"])
+    placed, cd_shards = _place_sharded(
+        mesh, axis, **{k: blocks[k] for k in ("dst", "src", "bf")})
+    dst, src, bf = placed["dst"], placed["src"], placed["bf"]
     state = dict(support=jnp.asarray(sup0.astype(np.int32)))
 
     def step(active: np.ndarray) -> np.ndarray:
@@ -1524,7 +1542,7 @@ def _distributed_tip_csr(
         theta, part, ranges, sup_init, stats,
         dict(cd_sharding="vertex_aligned" if aligned else "pair",
              n_parts=n_parts, n_wedges=wed.n_wedges,
-             n_pairs=wed.n_pairs, n_dev=n_dev),
+             n_pairs=wed.n_pairs, n_dev=n_dev, cd_shards=cd_shards),
         return_result)
 
 

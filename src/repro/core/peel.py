@@ -335,7 +335,7 @@ def _fd_wing_vmapped_pallas_rings(slot_e1, slot_e2, valid0, W0, mine, sup0,
 # =====================================================================
 # Fused FD bodies — the whole round is ONE Pallas launch
 # =====================================================================
-def _wing_fused_setup(slot_e1, slot_e2, valid0, W0, mine, sup0, interpret):
+def _wing_fused_setup(slot_e1, slot_e2, valid0, W0, mine, sup0):
     from repro.kernels import ops as kops  # local import: keep core light
 
     # loop-constant inits derived from inputs (cf. _fd_while_vmapped)
@@ -349,7 +349,7 @@ def _wing_fused_setup(slot_e1, slot_e2, valid0, W0, mine, sup0, interpret):
     def round_fn(sup, alive, theta, k, rounds, nupd, aslot, W):
         return kops.fd_round_wing(
             sup, alive, theta, k, rounds, nupd, aslot, W,
-            slot_e1, slot_e2, interpret=interpret)
+            slot_e1, slot_e2)
 
     return state0, round_fn
 
@@ -361,7 +361,6 @@ def _fd_wing_fused_impl(
     W0: jax.Array,          # (B, R) int32 — alive wedges per slot row
     mine: jax.Array,        # (B, E) bool
     sup0: jax.Array,        # (B, E) int32
-    interpret: bool = True,
 ):
     """Zero-per-round-dispatch wing FD: the while_loop body is ONE fused
     ``kernels.fd_round`` launch — k-advance, frontier compaction AND the
@@ -370,33 +369,31 @@ def _fd_wing_fused_impl(
     losses outside the kernel).  Returns (theta (B, E), rounds (B),
     update count) bit-identical to the unfused drivers."""
     state0, round_fn = _wing_fused_setup(
-        slot_e1, slot_e2, valid0, W0, mine, sup0, interpret)
+        slot_e1, slot_e2, valid0, W0, mine, sup0)
     out = peelspec._fd_while_fused(state0, round_fn)
     return out[2], out[4][:, 0], jnp.sum(out[5])
 
 
-_fd_wing_fused = partial(
-    jax.jit, static_argnames=("interpret",))(_fd_wing_fused_impl)
+_fd_wing_fused = jax.jit(_fd_wing_fused_impl)
 
 
 def _fd_wing_fused_rings_impl(slot_e1, slot_e2, valid0, W0, mine, sup0,
-                              interpret: bool, ring_cap: int):
+                              ring_cap: int):
     """:func:`_fd_wing_fused_impl` + per-round counter rings derived
     around the fused round (the kernel itself is untouched); the update
     ring carries the state's *cumulative* per-partition counts — drain
     with ``cumulative_updates=True``."""
     state0, round_fn = _wing_fused_setup(
-        slot_e1, slot_e2, valid0, W0, mine, sup0, interpret)
+        slot_e1, slot_e2, valid0, W0, mine, sup0)
     out, rings = peelspec._fd_while_fused_rings(state0, round_fn, ring_cap)
     return out[2], out[4][:, 0], jnp.sum(out[5]), rings
 
 
 _fd_wing_fused_rings = partial(
-    jax.jit,
-    static_argnames=("interpret", "ring_cap"))(_fd_wing_fused_rings_impl)
+    jax.jit, static_argnames=("ring_cap",))(_fd_wing_fused_rings_impl)
 
 
-def _tip_fused_setup(st_pa, st_pb, st_bf, mine, sup0, interpret):
+def _tip_fused_setup(st_pa, st_pb, st_bf, mine, sup0):
     from repro.kernels import ops as kops
 
     z = sup0 * 0
@@ -405,8 +402,7 @@ def _tip_fused_setup(st_pa, st_pb, st_bf, mine, sup0, interpret):
 
     def round_fn(sup, alive, theta, k, rounds):
         return kops.fd_round_tip(
-            sup, alive, theta, k, rounds, st_pa, st_pb, st_bf,
-            interpret=interpret)
+            sup, alive, theta, k, rounds, st_pa, st_pb, st_bf)
 
     return state0, round_fn
 
@@ -417,33 +413,30 @@ def _fd_tip_fused_impl(
     st_bf: jax.Array,       # (B, L) int32 — static pair ⋈ (0 on pad)
     mine: jax.Array,        # (B, E) bool
     sup0: jax.Array,        # (B, E) int32
-    interpret: bool = True,
 ):
     """Tip counterpart of :func:`_fd_wing_fused_impl`: one fused Pallas
     launch per round over the stacked partition-local pair lists.
     Returns (theta (B, E), rounds (B))."""
     state0, round_fn = _tip_fused_setup(
-        st_pa, st_pb, st_bf, mine, sup0, interpret)
+        st_pa, st_pb, st_bf, mine, sup0)
     out = peelspec._fd_while_fused(state0, round_fn)
     return out[2], out[4][:, 0]
 
 
-_fd_tip_fused = partial(
-    jax.jit, static_argnames=("interpret",))(_fd_tip_fused_impl)
+_fd_tip_fused = jax.jit(_fd_tip_fused_impl)
 
 
 def _fd_tip_fused_rings_impl(st_pa, st_pb, st_bf, mine, sup0,
-                             interpret: bool, ring_cap: int):
+                             ring_cap: int):
     """:func:`_fd_tip_fused_impl` + per-round counter rings (obs)."""
     state0, round_fn = _tip_fused_setup(
-        st_pa, st_pb, st_bf, mine, sup0, interpret)
+        st_pa, st_pb, st_bf, mine, sup0)
     out, rings = peelspec._fd_while_fused_rings(state0, round_fn, ring_cap)
     return out[2], out[4][:, 0], rings
 
 
 _fd_tip_fused_rings = partial(
-    jax.jit,
-    static_argnames=("interpret", "ring_cap"))(_fd_tip_fused_rings_impl)
+    jax.jit, static_argnames=("ring_cap",))(_fd_tip_fused_rings_impl)
 
 
 # =====================================================================
@@ -823,13 +816,11 @@ def _tip_spec_csr(
             cap = obs.fd_ring_cap()
             if cap:
                 theta_st, rounds, rings = _fd_tip_fused_rings(
-                    *f_args, interpret=kops.default_interpret(),
-                    ring_cap=cap)
+                    *f_args, ring_cap=cap)
                 _drain_rings("fused", [i], [int(rounds[0])], rings, cap,
                              cumulative=True)
             else:
-                theta_st, rounds = _fd_tip_fused(
-                    *f_args, interpret=kops.default_interpret())
+                theta_st, rounds = _fd_tip_fused(*f_args)
             mm = p["mine"][i]
             theta[p["gids"][i][mm]] = (
                 np.asarray(theta_st[0]).astype(np.int64)[mm])
@@ -960,15 +951,13 @@ def _tip_fd_vmapped_csr(
             theta_st, rounds, rings = _fd_tip_fused_rings(
                 jnp.asarray(packed["st_pa"]), jnp.asarray(packed["st_pb"]),
                 jnp.asarray(packed["st_bf"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]),
-                interpret=kops.default_interpret(), ring_cap=cap,
+                jnp.asarray(packed["sup0"]), ring_cap=cap,
             )
         else:
             theta_st, rounds = _fd_tip_fused(
                 jnp.asarray(packed["st_pa"]), jnp.asarray(packed["st_pb"]),
                 jnp.asarray(packed["st_bf"]), jnp.asarray(packed["mine"]),
                 jnp.asarray(packed["sup0"]),
-                interpret=kops.default_interpret(),
             )
     else:
         if cap:
@@ -1031,6 +1020,8 @@ def _wing_fd_vmapped_csr(
         W_rows = np.zeros((n_parts, R), dtype=np.int32)
         w = min(R, W0.shape[1])
         W_rows[:, :w] = W0[:, :w]
+        # the fused kernel picks its own (interpret-only) mode
+        kw = {} if fused else dict(interpret=kops.default_interpret())
         if cap:
             body = (_fd_wing_fused_rings if fused
                     else _fd_wing_vmapped_pallas_rings)
@@ -1039,7 +1030,7 @@ def _wing_fd_vmapped_csr(
                 jnp.asarray(packed["slot_e2"]),
                 jnp.asarray(packed["slot_valid"]), jnp.asarray(W_rows),
                 jnp.asarray(packed["mine"]), jnp.asarray(packed["sup0"]),
-                interpret=kops.default_interpret(), ring_cap=cap,
+                ring_cap=cap, **kw,
             )
         else:
             body = _fd_wing_fused if fused else _fd_wing_vmapped_pallas
@@ -1048,7 +1039,7 @@ def _wing_fd_vmapped_csr(
                 jnp.asarray(packed["slot_e2"]),
                 jnp.asarray(packed["slot_valid"]), jnp.asarray(W_rows),
                 jnp.asarray(packed["mine"]), jnp.asarray(packed["sup0"]),
-                interpret=kops.default_interpret(),
+                **kw,
             )
     else:
         if cap:
@@ -1364,13 +1355,11 @@ def _wing_spec_csr(
             cap = obs.fd_ring_cap()
             if cap:
                 theta_st, rounds, nupd, rings = _fd_wing_fused_rings(
-                    *f_args, interpret=kops.default_interpret(),
-                    ring_cap=cap)
+                    *f_args, ring_cap=cap)
                 _drain_rings("fused", [i], [int(rounds[0])], rings, cap,
                              cumulative=True)
             else:
-                theta_st, rounds, nupd = _fd_wing_fused(
-                    *f_args, interpret=kops.default_interpret())
+                theta_st, rounds, nupd = _fd_wing_fused(*f_args)
             mm = p["mine"][i]
             theta[p["gids"][i][mm]] = (
                 np.asarray(theta_st[0]).astype(np.int64)[mm])

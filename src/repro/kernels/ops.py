@@ -275,11 +275,13 @@ def support_update(
     return c1[:n, :kdim], c2[:n, :kdim], c[:n]
 
 
-def _require_interpret(interpret: bool | None) -> None:
-    """The fused FD round runs in interpret mode or not at all."""
-    if not _interpret(interpret):
+def _refuse_on_tpu() -> None:
+    """The fused FD round runs in interpret mode off a TPU, or not at
+    all: it has no Mosaic lowering and interpret mode is refused on a
+    TPU backend."""
+    if jax.default_backend() == "tpu":
         raise NotImplementedError(
-            f"the fused FD round kernel cannot run compiled: {MOSAIC_LIMITS}")
+            f"the fused FD round kernel cannot run on a TPU: {MOSAIC_LIMITS}")
 
 
 # The fd_round wrappers are deliberately NOT jitted: they only ever run
@@ -287,8 +289,7 @@ def _require_interpret(interpret: bool | None) -> None:
 # consumers), where a nested pjit would wrap the pallas_call and obscure
 # the round body's jaxpr — tests assert that body is exactly ONE
 # pallas_call and nothing else (tests/test_fused_fd.py).
-def fd_round_wing(sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2,
-                  interpret: bool | None = None):
+def fd_round_wing(sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2):
     """One fused wing-FD round (k-advance + frontier compaction + widow/
     survivor support update) as a single Pallas launch.
 
@@ -297,14 +298,13 @@ def fd_round_wing(sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2,
     ``e1``/``e2`` are the static (B, R, K) local edge ids with sentinel
     E (``distributed._pack_fd_slots_csr``).  Interpret mode only: the
     kernel has no Mosaic lowering (``fd_round.MOSAIC_LIMITS``)."""
-    _require_interpret(interpret)
+    _refuse_on_tpu()
     return fd_round_wing_pallas(
         sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2,
         interpret=True)
 
 
-def fd_round_tip(sup, alive, theta, k, rounds, pa, pb, bf,
-                 interpret: bool | None = None):
+def fd_round_tip(sup, alive, theta, k, rounds, pa, pb, bf):
     """One fused tip-FD round as a single Pallas launch.
 
     State in/out (same order): sup/alive/theta (B, E) i32, k/rounds
@@ -312,7 +312,7 @@ def fd_round_tip(sup, alive, theta, k, rounds, pa, pb, bf,
     local pair lists (``pack_fd_partitions_tip_csr(stacked=True)``;
     bf=0 padding is algebra-neutral).  Interpret mode only, like
     :func:`fd_round_wing`."""
-    _require_interpret(interpret)
+    _refuse_on_tpu()
     return fd_round_tip_pallas(
         sup, alive, theta, k, rounds, pa, pb, bf, interpret=True)
 

@@ -317,7 +317,7 @@ def _dryrun() -> int:
     W_rows = np.zeros((B_f, R_f), np.int32)
     w_f = min(R_f, packed_f["W0"].shape[1])
     W_rows[:, :w_f] = packed_f["W0"][:, :w_f]
-    fj = jax.make_jaxpr(lambda *a: _fd_wing_fused_impl(*a, interpret=True))(
+    fj = jax.make_jaxpr(_fd_wing_fused_impl)(
         jnp.asarray(packed_f["slot_e1"]), jnp.asarray(packed_f["slot_e2"]),
         jnp.asarray(packed_f["slot_valid"]), jnp.asarray(W_rows),
         jnp.asarray(packed_f["mine"]), jnp.asarray(packed_f["sup0"]))

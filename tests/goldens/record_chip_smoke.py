@@ -13,8 +13,8 @@ array.  Everything is integer peeling, so the digests are
 machine-independent: a TPU run of the same path must reproduce them bit
 for bit.
 
-Run on the CPU from the repo root (a few minutes, under 2 GB of host
-memory)::
+Run on the CPU from the repo root (about three minutes, under 4 GB of
+host memory)::
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python tests/goldens/record_chip_smoke.py
 
@@ -37,12 +37,16 @@ from record_stream_goldens import forest_digest  # noqa: E402
 
 # The power-law generator at the degree skew the paper targets (alpha
 # 0.7, |U|:|V| = 4:1), cut from 200k x 50k x 1M (1.59e8 V-centred
-# wedges) to fit a chip run's time limit (see CHANGES.md):
-#   peel   1/10: 6,192,214 wedges, max degrees 840 (U) / 1,655 (V);
-#   forest 1/20: 2,386,826 wedges, the largest whose wing forest builds
-#          at the default level_block in seconds on one chip.
+# wedges), whose wing peel did not finish within 700 s on one v5e:
+#   peel   1/5:  16,483,275 wedges, max degrees 1,404 (U) / 2,740 (V);
+#   forest 1/20:  2,386,826 wedges.  The forest is not built from the
+#          peel graph: there the wing label program fits the chip's HBM
+#          only at level_block 1 (~0.6 KB per incidence at any block
+#          >= 2, ~20 GiB for its 33M incidences), and that build alone
+#          takes longer on one v5e than phases (a) and (b) leave of the
+#          smoke run's 1200 s.
 GRAPHS = dict(
-    peel=dict(n_u=20_000, n_v=5_000, m=100_000, alpha=0.7, seed=0),
+    peel=dict(n_u=40_000, n_v=10_000, m=200_000, alpha=0.7, seed=0),
     forest=dict(n_u=10_000, n_v=2_500, m=50_000, alpha=0.7, seed=0),
 )
 KINDS = ("wing", "tip")

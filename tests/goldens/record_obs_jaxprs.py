@@ -113,8 +113,7 @@ def fused_wing_jaxpr() -> str:
     from repro.core.peel import _fd_wing_fused_impl
 
     p, _ = _wing_pack()
-    return str(jax.make_jaxpr(
-        lambda *a: _fd_wing_fused_impl(*a, interpret=True))(
+    return str(jax.make_jaxpr(_fd_wing_fused_impl)(
         jnp.asarray(p["slot_e1"]), jnp.asarray(p["slot_e2"]),
         jnp.asarray(p["slot_valid"]), jnp.asarray(p["W_rows"]),
         jnp.asarray(p["mine"]), jnp.asarray(p["sup0"]))).strip()
@@ -127,8 +126,7 @@ def fused_tip_jaxpr() -> str:
     from repro.core.peel import _fd_tip_fused_impl
 
     p, _ = _tip_pack()
-    return str(jax.make_jaxpr(
-        lambda *a: _fd_tip_fused_impl(*a, interpret=True))(
+    return str(jax.make_jaxpr(_fd_tip_fused_impl)(
         jnp.asarray(p["st_pa"]), jnp.asarray(p["st_pb"]),
         jnp.asarray(p["st_bf"]), jnp.asarray(p["mine"]),
         jnp.asarray(p["sup0"]))).strip()

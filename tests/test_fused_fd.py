@@ -94,7 +94,7 @@ def test_fd_round_wing_kernel_matches_ref(seed):
     state, statics, _ = _wing_state(seed=seed)
     # iterate to the fixed point: every round's full 8-tuple must agree
     for _ in range(40):
-        got = ops.fd_round_wing(*state, *statics, interpret=True)
+        got = ops.fd_round_wing(*state, *statics)
         want = ref.fd_round_wing_ref(*state, *statics)
         for i, (a, b) in enumerate(zip(got, want)):
             np.testing.assert_array_equal(
@@ -109,7 +109,7 @@ def test_fd_round_wing_kernel_matches_ref(seed):
 def test_fd_round_tip_kernel_matches_ref(seed):
     state, statics, _ = _tip_state(seed=seed)
     for _ in range(40):
-        got = ops.fd_round_tip(*state, *statics, interpret=True)
+        got = ops.fd_round_tip(*state, *statics)
         want = ref.fd_round_tip_ref(*state, *statics)
         for i, (a, b) in enumerate(zip(got, want)):
             np.testing.assert_array_equal(
@@ -128,16 +128,14 @@ def test_wing_round_wrapper_is_single_pallas_call():
     pallas_call — nothing before it, nothing after it (this is why the
     wrapper is deliberately unjitted)."""
     state, statics, _ = _wing_state()
-    jx = jax.make_jaxpr(
-        lambda *a: ops.fd_round_wing(*a, interpret=True))(*state, *statics)
+    jx = jax.make_jaxpr(ops.fd_round_wing)(*state, *statics)
     prims = [e.primitive.name for e in jx.jaxpr.eqns]
     assert prims == ["pallas_call"], prims
 
 
 def test_tip_round_wrapper_is_single_pallas_call():
     state, statics, _ = _tip_state()
-    jx = jax.make_jaxpr(
-        lambda *a: ops.fd_round_tip(*a, interpret=True))(*state, *statics)
+    jx = jax.make_jaxpr(ops.fd_round_tip)(*state, *statics)
     prims = [e.primitive.name for e in jx.jaxpr.eqns]
     assert prims == ["pallas_call"], prims
 
@@ -156,9 +154,7 @@ def test_fused_wing_phase_is_one_while_one_pallas_call():
     pallas_call — the zero-per-round-dispatch claim, stated on the
     jaxpr."""
     state, statics, p = _wing_state()
-    _assert_fused_phase_structure(jax.make_jaxpr(
-        lambda e1, e2, v, w, mi, s: _fd_wing_fused_impl(
-            e1, e2, v, w, mi, s, interpret=True))(
+    _assert_fused_phase_structure(jax.make_jaxpr(_fd_wing_fused_impl)(
         statics[0], statics[1], jnp.asarray(p["slot_valid"]),
         state[7].astype(jnp.int32), jnp.asarray(p["mine"]),
         jnp.asarray(p["sup0"])))
@@ -166,9 +162,7 @@ def test_fused_wing_phase_is_one_while_one_pallas_call():
 
 def test_fused_tip_phase_is_one_while_one_pallas_call():
     state, statics, p = _tip_state()
-    _assert_fused_phase_structure(jax.make_jaxpr(
-        lambda pa, pb, bf, mi, s: _fd_tip_fused_impl(
-            pa, pb, bf, mi, s, interpret=True))(
+    _assert_fused_phase_structure(jax.make_jaxpr(_fd_tip_fused_impl)(
         *statics, jnp.asarray(p["mine"]), jnp.asarray(p["sup0"])))
 
 

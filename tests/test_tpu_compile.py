@@ -1,7 +1,7 @@
 """Compile the main path's kernels and the unfused csr FD program for a
 described TPU v5e, at the widths of ``chip_smoke.py``'s phase-(b) graph
 (``tests/goldens/record_chip_smoke.py``'s ``peel`` graph: |E| =
-100,000, 6,192,214 V-centred wedges in 5,709,922 pairs).
+200,000, 16,483,275 V-centred wedges in 15,423,111 pairs).
 
 Nothing runs: the TPU compiler is installed on CPU-only machines and
 compiles for a chip that is described, not attached, so Mosaic's tiling
@@ -21,16 +21,16 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.peel import _fd_tip_device, _fd_wing_device
 from repro.kernels import ops as kops
 
-# phase-(b) widths: W0 max 133 wedges per pair → 256 slot lanes (wing);
-# a U vertex sits in at most 11,734 directed pairs → 11,776 lanes (tip);
+# phase-(b) widths: W0 max 169 wedges per pair → 256 slot lanes (wing);
+# a U vertex sits in at most 20,514 directed pairs → 20,608 lanes (tip);
 # the tiled ⋈init packs 512-wide slot rows; the dense adjacency is
-# 20,000 × 5,000.  Row counts are the largest one launch sees, or a
+# 40,000 × 10,000.  Row counts are the largest one launch sees, or a
 # 2^16-row stripe where the whole-graph slot layout would crowd HBM
-# (wing: 5.7M rows × 256 lanes).
-M, N_U, N_V = 100_000, 20_000, 5_000
-N_PAIRS = 5_709_922
-WEDGE_BUCKET = 6_291_456       # peelspec._bucket_pad(6_192_214)
-WING_LANES, TIP_LANES, TILE_LANES = 256, 11_776, 512
+# (wing: 15.4M rows × 256 lanes).
+M, N_U, N_V = 200_000, 40_000, 10_000
+N_PAIRS = 15_423_111
+WEDGE_BUCKET = 16_777_216      # peelspec._bucket_pad(16_483_275)
+WING_LANES, TIP_LANES, TILE_LANES = 256, 20_608, 512
 STRIPE = 1 << 16
 HBM_BYTES = 15.75 * 2 ** 30    # v5e HBM as its compiler reports it
 
@@ -88,8 +88,8 @@ KERNELS = {
         _s(s, (N_U, N_V), jnp.float32), interpret=False),
     # one 1024-row strip against the padded adjacency
     "vertex_count_tile": lambda s: kops._vertex_tile_inner.lower(
-        _s(s, (1024, 5_120), jnp.float32),
-        _s(s, (20_096, 5_120), jnp.float32), 128, 128, False),
+        _s(s, (1024, 10_112), jnp.float32),
+        _s(s, (40_064, 10_112), jnp.float32), 128, 128, False),
 }
 
 
