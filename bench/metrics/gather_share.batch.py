@@ -1,0 +1,11 @@
+"""gather_share.batch: time of device operations that gather (their
+``tf_op`` ends in a gather), as a share of device busy time in the
+traced job (%)."""
+
+
+def read(ctx):
+    """The metric from the run's context, or None."""
+    tr = ctx.get("trace")
+    if not tr or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * tr["gather_s"] / tr["busy_s"]
