@@ -797,22 +797,23 @@ def _tip_spec_csr(
         if fused and fd_driver == "device":
             from repro.kernels import ops as kops
 
-            if "p" not in fused_pack:
-                from .distributed import pack_fd_partitions_tip_csr
+            with obs.span("fd.pack", cat="fd.pack"):
+                if "p" not in fused_pack:
+                    from .distributed import pack_fd_partitions_tip_csr
 
-                fused_pack["p"] = pack_fd_partitions_tip_csr(
-                    wed, pair_bf0, part, sup_init,
-                    int(part.max()) + 1 if part.size else 0,
-                    bucket=True, stacked=True,
+                    fused_pack["p"] = pack_fd_partitions_tip_csr(
+                        wed, pair_bf0, part, sup_init,
+                        int(part.max()) + 1 if part.size else 0,
+                        bucket=True, stacked=True,
+                    )
+                p = fused_pack["p"]
+                f_args = (
+                    jnp.asarray(p["st_pa"][i:i + 1]),
+                    jnp.asarray(p["st_pb"][i:i + 1]),
+                    jnp.asarray(p["st_bf"][i:i + 1]),
+                    jnp.asarray(p["mine"][i:i + 1]),
+                    jnp.asarray(p["sup0"][i:i + 1]),
                 )
-            p = fused_pack["p"]
-            f_args = (
-                jnp.asarray(p["st_pa"][i:i + 1]),
-                jnp.asarray(p["st_pb"][i:i + 1]),
-                jnp.asarray(p["st_bf"][i:i + 1]),
-                jnp.asarray(p["mine"][i:i + 1]),
-                jnp.asarray(p["sup0"][i:i + 1]),
-            )
             cap = obs.fd_ring_cap()
             if cap:
                 theta_st, rounds, rings = _fd_tip_fused_rings(
@@ -864,27 +865,35 @@ def _tip_fd_csr(
     partition, zero host round-trips.  ``"host"`` keeps the per-round
     dispatch loop (the PR-1 baseline, benchmarked against).
     """
-    mine = part == i
-    if not mine.any():
-        return 0
-    n = part.size
-    mask = mine[wed.pair_a] & mine[wed.pair_b] if wed.n_pairs else np.zeros(0, bool)
+    with obs.span("fd.pack", cat="fd.pack"):
+        mine = part == i
+        if not mine.any():
+            return 0
+        n = part.size
+        mask = (mine[wed.pair_a] & mine[wed.pair_b] if wed.n_pairs
+                else np.zeros(0, bool))
 
-    support0 = np.zeros(n, dtype=np.int64)
-    support0[mine] = sup_init[mine]
+        support0 = np.zeros(n, dtype=np.int64)
+        support0[mine] = sup_init[mine]
+        if fd_driver == "device":
+            # bucket-pad the pair arrays so the while_loop compiles once
+            # per size bucket, not once per partition
+            size = _bucket_pad(int(mask.sum()))
+            args = (
+                jnp.asarray(mine), jnp.asarray(support0.astype(np.int32)),
+                jnp.asarray(_pad_zeros(wed.pair_a[mask], size)),
+                jnp.asarray(_pad_zeros(wed.pair_b[mask], size)),
+                jnp.asarray(_pad_zeros(pair_bf0[mask].astype(np.int32),
+                                       size)),
+                n,
+            )
+        else:
+            pa = jnp.asarray(wed.pair_a[mask])
+            pb = jnp.asarray(wed.pair_b[mask])
+            pbf = jnp.asarray(pair_bf0[mask].astype(np.int32))
 
     cap = obs.fd_ring_cap()
     if fd_driver == "device":
-        # bucket-pad the pair arrays so the while_loop compiles once per
-        # size bucket, not once per partition
-        size = _bucket_pad(int(mask.sum()))
-        args = (
-            jnp.asarray(mine), jnp.asarray(support0.astype(np.int32)),
-            jnp.asarray(_pad_zeros(wed.pair_a[mask], size)),
-            jnp.asarray(_pad_zeros(wed.pair_b[mask], size)),
-            jnp.asarray(_pad_zeros(pair_bf0[mask].astype(np.int32), size)),
-            n,
-        )
         if cap:
             theta_d, rounds, _, rings = _fd_tip_device_rings(
                 *args, ring_cap=cap)
@@ -894,10 +903,6 @@ def _tip_fd_csr(
         theta_np = np.asarray(theta_d).astype(np.int64)
         theta[mine] = theta_np[mine]
         return int(rounds)
-
-    pa = jnp.asarray(wed.pair_a[mask])
-    pb = jnp.asarray(wed.pair_b[mask])
-    pbf = jnp.asarray(pair_bf0[mask].astype(np.int32))
 
     def peel(S, sup):
         delta = np.asarray(
@@ -1331,27 +1336,29 @@ def _wing_spec_csr(
         if fused and fd_driver == "device":
             from repro.kernels import ops as kops
 
-            if "p" not in fused_pack:
-                from .distributed import pack_fd_partitions_csr
+            with obs.span("fd.pack", cat="fd.pack"):
+                if "p" not in fused_pack:
+                    from .distributed import pack_fd_partitions_csr
 
-                n_parts = int(part.max()) + 1 if part.size else 0
-                p = pack_fd_partitions_csr(
-                    wed, part, sup_init, n_parts, bucket=True, slots=True)
-                R, _ = p["slot_sizes"]
-                W_rows = np.zeros((n_parts, R), dtype=np.int32)
-                w = min(R, p["W0"].shape[1])
-                W_rows[:, :w] = p["W0"][:, :w]
-                p["W_rows"] = W_rows
-                fused_pack["p"] = p
-            p = fused_pack["p"]
-            f_args = (
-                jnp.asarray(p["slot_e1"][i:i + 1]),
-                jnp.asarray(p["slot_e2"][i:i + 1]),
-                jnp.asarray(p["slot_valid"][i:i + 1]),
-                jnp.asarray(p["W_rows"][i:i + 1]),
-                jnp.asarray(p["mine"][i:i + 1]),
-                jnp.asarray(p["sup0"][i:i + 1]),
-            )
+                    n_parts = int(part.max()) + 1 if part.size else 0
+                    p = pack_fd_partitions_csr(
+                        wed, part, sup_init, n_parts, bucket=True,
+                        slots=True)
+                    R, _ = p["slot_sizes"]
+                    W_rows = np.zeros((n_parts, R), dtype=np.int32)
+                    w = min(R, p["W0"].shape[1])
+                    W_rows[:, :w] = p["W0"][:, :w]
+                    p["W_rows"] = W_rows
+                    fused_pack["p"] = p
+                p = fused_pack["p"]
+                f_args = (
+                    jnp.asarray(p["slot_e1"][i:i + 1]),
+                    jnp.asarray(p["slot_e2"][i:i + 1]),
+                    jnp.asarray(p["slot_valid"][i:i + 1]),
+                    jnp.asarray(p["W_rows"][i:i + 1]),
+                    jnp.asarray(p["mine"][i:i + 1]),
+                    jnp.asarray(p["sup0"][i:i + 1]),
+                )
             cap = obs.fd_ring_cap()
             if cap:
                 theta_st, rounds, nupd, rings = _fd_wing_fused_rings(
@@ -1444,48 +1451,56 @@ def _wing_fd_csr(
     ``lax.while_loop`` (:func:`_fd_wing_device`); ``"host"`` keeps the
     per-round dispatch loop (the PR-1 baseline, benchmarked against).
     """
-    mine = part == i
-    if not mine.any():
-        return 0, 0
-    m = part.size
-    n_pairs = wed.n_pairs
-    if wed.n_wedges:
-        p1 = part[wed.wedge_e1]
-        p2 = part[wed.wedge_e2]
-        keep_ge = (p1 >= i) & (p2 >= i)
-        # only wedges TOUCHING partition i can die during FD_i; the
-        # untouched ≥i wedges stay alive all phase and their survivor
-        # charges land on discarded later-partition edges — fold them
-        # into the static W_p init instead of carrying them (exact; see
-        # distributed.pack_fd_partitions_csr)
-        keep = keep_ge & (np.minimum(p1, p2) == i)
-    else:
-        keep_ge = keep = np.zeros(0, bool)
-    Wp = jnp.asarray(
-        np.bincount(
-            wed.wedge_pair[keep_ge], minlength=max(n_pairs, 1)
-        ).astype(np.int32)
-    )
+    with obs.span("fd.pack", cat="fd.pack"):
+        mine = part == i
+        if not mine.any():
+            return 0, 0
+        m = part.size
+        n_pairs = wed.n_pairs
+        if wed.n_wedges:
+            p1 = part[wed.wedge_e1]
+            p2 = part[wed.wedge_e2]
+            keep_ge = (p1 >= i) & (p2 >= i)
+            # only wedges TOUCHING partition i can die during FD_i; the
+            # untouched ≥i wedges stay alive all phase and their survivor
+            # charges land on discarded later-partition edges — fold them
+            # into the static W_p init instead of carrying them (exact;
+            # see distributed.pack_fd_partitions_csr)
+            keep = keep_ge & (np.minimum(p1, p2) == i)
+        else:
+            keep_ge = keep = np.zeros(0, bool)
+        Wp = jnp.asarray(
+            np.bincount(
+                wed.wedge_pair[keep_ge], minlength=max(n_pairs, 1)
+            ).astype(np.int32)
+        )
 
-    support_full = np.zeros(m, dtype=np.int64)
-    support_full[mine] = sup_init[mine]
+        support_full = np.zeros(m, dtype=np.int64)
+        support_full[mine] = sup_init[mine]
+        if fd_driver == "device":
+            # bucket-pad the wedge arrays (dead zero wedges are inert) so
+            # the while_loop compiles once per size bucket
+            n_kept = int(keep.sum())
+            size = _bucket_pad(n_kept)
+            alive_w = np.zeros(size, dtype=bool)
+            alive_w[:n_kept] = True
+            args = (
+                jnp.asarray(mine), jnp.asarray(support_full.astype(np.int32)),
+                jnp.asarray(alive_w), Wp,
+                jnp.asarray(_pad_zeros(wed.wedge_e1[keep], size)),
+                jnp.asarray(_pad_zeros(wed.wedge_e2[keep], size)),
+                jnp.asarray(_pad_zeros(wed.wedge_pair[keep], size)),
+                n_pairs, m,
+            )
+        else:
+            kwe1 = jnp.asarray(wed.wedge_e1[keep])
+            kwe2 = jnp.asarray(wed.wedge_e2[keep])
+            kwp = jnp.asarray(wed.wedge_pair[keep])
+            alive_w = jnp.ones((int(keep.sum()),), dtype=bool)
+            support = jnp.asarray(support_full.astype(np.int32))
 
     cap = obs.fd_ring_cap()
     if fd_driver == "device":
-        # bucket-pad the wedge arrays (dead zero wedges are inert) so
-        # the while_loop compiles once per size bucket
-        n_kept = int(keep.sum())
-        size = _bucket_pad(n_kept)
-        alive_w = np.zeros(size, dtype=bool)
-        alive_w[:n_kept] = True
-        args = (
-            jnp.asarray(mine), jnp.asarray(support_full.astype(np.int32)),
-            jnp.asarray(alive_w), Wp,
-            jnp.asarray(_pad_zeros(wed.wedge_e1[keep], size)),
-            jnp.asarray(_pad_zeros(wed.wedge_e2[keep], size)),
-            jnp.asarray(_pad_zeros(wed.wedge_pair[keep], size)),
-            n_pairs, m,
-        )
         if cap:
             theta_d, rounds, nupd, rings = _fd_wing_device_rings(
                 *args, ring_cap=cap)
@@ -1496,12 +1511,6 @@ def _wing_fd_csr(
         theta[mine] = theta_np[mine]
         return int(rounds), int(nupd)
 
-    kwe1 = jnp.asarray(wed.wedge_e1[keep])
-    kwe2 = jnp.asarray(wed.wedge_e2[keep])
-    kwp = jnp.asarray(wed.wedge_pair[keep])
-    alive_w = jnp.ones((int(keep.sum()),), dtype=bool)
-
-    support = jnp.asarray(support_full.astype(np.int32))
     nupd = 0
 
     def peel(S, sup):

@@ -263,11 +263,15 @@ def cd_loop(spec: PeelSpec, P: int, stats: PeelStats, target=None):
     synchronization points), and the engine's ``cd_step`` charges its
     own update/recount counters.
 
-    When the obs layer is collecting (``obs.maybe_collect`` installed a
-    collector), every inner round is additionally wrapped in a
-    ``cd.round`` span and recorded into the run's timeline — span count
-    == ``stats.rho_cd`` by construction.  CD is host-driven, so this is
-    pure host bookkeeping: device programs are untouched either way."""
+    With the obs layer on, each partition's range selection is a
+    ``cd.select`` span (count == ``p_effective``) and every inner round
+    a ``cd.round`` span around a ``cd.step`` span (the engine's
+    ``cd_step``: mask upload, update, readback); both counts ==
+    ``stats.rho_cd`` by construction.  When a timeline collector is live
+    (``obs.maybe_collect``) each round is also recorded into the run's
+    timeline.  CD is host-driven, so this is pure host bookkeeping:
+    device programs are untouched either way."""
+    traced = obs.enabled()
     col = obs.active_collector()
     sup_np = np.asarray(spec.sup0, dtype=np.int64).copy()
     n = sup_np.size
@@ -281,14 +285,17 @@ def cd_loop(spec: PeelSpec, P: int, stats: PeelStats, target=None):
     for i in range(P):
         if not alive.any():
             break
-        sup_init[alive] = sup_np[alive]
-        if i == P - 1:
-            hi = int(sup_np[alive].max()) + 1
-        else:
-            tgt = target.target(i)
-            hi = _find_range(sup_np, spec.workload(sup_np), alive, tgt)
-            hi = max(hi, int(sup_np[alive].min()) + 1)  # guarantee progress
-        initial_est = float(spec.est(sup_np)[alive & (sup_np < hi)].sum())
+        with obs.span("cd.select", cat="cd.select") as sel:
+            sup_init[alive] = sup_np[alive]
+            if i == P - 1:
+                hi = int(sup_np[alive].max()) + 1
+            else:
+                tgt = target.target(i)
+                hi = _find_range(sup_np, spec.workload(sup_np), alive, tgt)
+                hi = max(hi, int(sup_np[alive].min()) + 1)  # guarantee progress
+            initial_est = float(spec.est(sup_np)[alive & (sup_np < hi)].sum())
+            if sel is not None:
+                sel.update(part=i, hi=hi)
         ranges.append(hi)
 
         # ---- inner peeling rounds for range [θ(i), hi)
@@ -298,20 +305,22 @@ def cd_loop(spec: PeelSpec, P: int, stats: PeelStats, target=None):
                 break
             part[active] = i
             alive &= ~active
-            if col is None:
+            if not traced:
                 sup_np = spec.cd_step(active)
             else:
                 died = int(active.sum())
                 u0, r0 = stats.updates, stats.recounts
-                with obs.span("cd.round", cat="cd.round",
-                              part=int(i)) as sp:
-                    sup_np = spec.cd_step(active)
+                with obs.span("cd.round", cat="cd.round") as sp:
+                    with obs.span("cd.step", cat="cd.step") as st:
+                        sup_np = spec.cd_step(active)
+                        st.update(part=i, died=died)
                     frontier = int(alive.sum())
                     du = stats.updates - u0
                     dr = stats.recounts - r0
-                    sp.update(died=died, frontier=frontier, hi=int(hi),
-                              updates=du, recounts=dr)
-                col.record_cd_round(i, died, frontier, int(hi), du, dr)
+                    sp.update(part=i, died=died, frontier=frontier,
+                              hi=int(hi), updates=du, recounts=dr)
+                if col is not None:
+                    col.record_cd_round(i, died, frontier, int(hi), du, dr)
             stats.rho_cd += 1
 
         final_est = float(spec.est(sup_init)[part == i].sum())
@@ -360,12 +369,12 @@ def run_fd(
                 "only= requires a per-partition fd_driver "
                 "('device' | 'host'); the vmapped driver dispatches "
                 "every partition in one launch")
-        with obs.span("fd.vmapped", cat="fd.launch",
-                      n_parts=int(n_parts)) as sp:
+        with obs.span("fd.vmapped", cat="fd.launch") as sp:
             rounds_v, nupd = spec.fd_vmapped(part, sup_init, theta, n_parts)
             rounds_v = np.asarray(rounds_v)
             if sp is not None:
-                sp.update(rounds=int(rounds_v.sum()), updates=int(nupd))
+                sp.update(n_parts=int(n_parts), rounds=int(rounds_v.sum()),
+                          updates=int(nupd))
         stats.rho_fd_total = int(rounds_v.sum())
         stats.rho_fd_max = int(rounds_v.max()) if rounds_v.size else 0
         stats.updates += int(nupd)
@@ -383,12 +392,11 @@ def run_fd(
     )
     for j in _lpt_order(part_work):
         i = int(ids[j])
-        with obs.span(f"fd.partition[{i}]", cat="fd.launch",
-                      part=i) as sp:
+        with obs.span("fd.partition", cat="fd.launch") as sp:
             rounds, nupd, nrec = spec.fd_partition(
                 i, part, sup_init, theta, fd_driver)
             if sp is not None:
-                sp.update(rounds=int(rounds), updates=int(nupd),
+                sp.update(part=i, rounds=int(rounds), updates=int(nupd),
                           recounts=int(nrec))
         if per_partition is not None:
             per_partition[i] = (int(rounds), int(nupd), int(nrec))
@@ -410,10 +418,10 @@ def decompose(
     ``wing_decomposition`` (every engine).
 
     When the obs layer is enabled this is also the telemetry root: it
-    installs the timeline collector, wraps the run in a ``peel`` span
-    with ``cd``/``fd`` phase spans, and attaches the built
-    :class:`~repro.obs.PeelTimeline` to the result (synthesizing the
-    per-round ``fd.round`` trace events from the drained rings)."""
+    wraps the run in a ``peel`` span with ``cd``/``fd`` phase spans and,
+    in timeline mode, installs the timeline collector and attaches the
+    built :class:`~repro.obs.PeelTimeline` to the result (synthesizing
+    the per-round ``fd.round`` trace events from the drained rings)."""
     with obs.maybe_collect() as col:
         with obs.span("peel.decompose", cat="peel", kind=spec.kind,
                       engine=stats.engine, fd_driver=fd_driver, P=int(P)):

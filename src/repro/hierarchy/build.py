@@ -206,25 +206,25 @@ def _component_labels_per_level(
     if L == 0 or n_ent == 0:
         return np.zeros((0, n_ent), dtype=np.int64)
 
-    if kind == "wing":
+    with obs.span("hierarchy.wedges", cat="hierarchy"):
         wed = csr.build_wedges(gg)
-        we1 = jnp.asarray(wed.wedge_e1)
-        we2 = jnp.asarray(wed.wedge_e2)
-        wp = jnp.asarray(wed.wedge_pair)
-        inc_e = jnp.concatenate([we1, we2])
-        inc_g = jnp.concatenate([wp, wp])
-        n_groups = wed.n_pairs
-    else:
-        wed = csr.build_wedges(gg)
-        # pairs with ≥ 2 wedges share a butterfly (V is never peeled, so
-        # W0 is the pair's wedge count at every level)
-        conn_p = wed.W0 >= 2
-        pa = wed.pair_a[conn_p].astype(np.int32)
-        pb = wed.pair_b[conn_p].astype(np.int32)
-        pid = np.arange(pa.size, dtype=np.int32)
-        inc_e = jnp.asarray(np.concatenate([pa, pb]))
-        inc_g = jnp.asarray(np.concatenate([pid, pid]))
-        n_groups = int(pa.size)
+        if kind == "wing":
+            we1 = jnp.asarray(wed.wedge_e1)
+            we2 = jnp.asarray(wed.wedge_e2)
+            wp = jnp.asarray(wed.wedge_pair)
+            inc_e = jnp.concatenate([we1, we2])
+            inc_g = jnp.concatenate([wp, wp])
+            n_groups = wed.n_pairs
+        else:
+            # pairs with ≥ 2 wedges share a butterfly (V is never peeled,
+            # so W0 is the pair's wedge count at every level)
+            conn_p = wed.W0 >= 2
+            pa = wed.pair_a[conn_p].astype(np.int32)
+            pb = wed.pair_b[conn_p].astype(np.int32)
+            pid = np.arange(pa.size, dtype=np.int32)
+            inc_e = jnp.asarray(np.concatenate([pa, pb]))
+            inc_g = jnp.asarray(np.concatenate([pid, pid]))
+            n_groups = int(pa.size)
 
     ids = jnp.arange(n_ent, dtype=jnp.int32)[None, :]
     out = np.empty((L, n_ent), dtype=np.int64)
@@ -280,8 +280,11 @@ def build_hierarchy(
 ) -> Hierarchy:
     """Construct the k-wing / k-tip hierarchy forest from peel output.
 
-    Traced under a ``hierarchy``-cat span (labeling / node creation /
-    per-node stats sub-spans) when the obs layer is enabled.
+    Traced under a ``hierarchy.build`` span when the obs layer is
+    enabled: ``hierarchy.labels`` (the device label program, with the
+    wedge rebuild and its uploads as ``hierarchy.wedges``) and
+    ``hierarchy.assemble`` (the host assembly, with the per-node loop as
+    ``hierarchy.node_stats``).
 
     ``result`` is a :class:`~repro.core.peel.PeelResult` from ANY engine
     (``dense`` / ``beindex`` / ``csr`` — their θ are bit-identical, so
@@ -317,13 +320,15 @@ def _build_hierarchy_impl(g, result, kind, side, meta, level_block):
         )
 
     levels = np.unique(theta[theta > 0])
-    with obs.span("hierarchy.labels", cat="hierarchy",
-                  levels=int(levels.size)):
+    with obs.span("hierarchy.labels", cat="hierarchy") as sp:
+        if sp is not None:
+            sp.update(levels=int(levels.size))
         labels = _component_labels_per_level(
             gg, theta, levels, kind, level_block=level_block
         )
-    return _assemble_from_labels(
-        gg, theta, levels, labels, kind, side, prov, meta)
+    with obs.span("hierarchy.assemble", cat="hierarchy"):
+        return _assemble_from_labels(
+            gg, theta, levels, labels, kind, side, prov, meta)
 
 
 def _assemble_from_labels(
@@ -396,8 +401,9 @@ def _assemble_from_labels(
     node_m = np.zeros(n_nodes, dtype=np.int64)
     node_nu = np.zeros(n_nodes, dtype=np.int64)
     node_nv = np.zeros(n_nodes, dtype=np.int64)
-    with obs.span("hierarchy.node_stats", cat="hierarchy",
-                  n_nodes=int(n_nodes)):
+    with obs.span("hierarchy.node_stats", cat="hierarchy") as sp:
+        if sp is not None:
+            sp.update(n_nodes=int(n_nodes))
         if kind == "wing":
             eu = gg.edges[:, 0]
             ev = gg.edges[:, 1]

@@ -174,76 +174,91 @@ class MultiTenantService:
         and args → int32 answers.  Slots are grouped by shape bucket
         and each group dispatches in fixed ``batch``-slot chunks.  Used
         directly by benchmarks; :meth:`run` wraps it."""
-        ops = np.asarray(ops, dtype=np.int32)
-        a = np.asarray(a, dtype=np.int32)
-        b = np.zeros_like(a) if b is None else np.asarray(b, dtype=np.int32)
-        tenants = list(tenants)
-        if not (len(tenants) == ops.size == a.size == b.size):
-            raise ValueError("tenants/ops/a/b must be parallel arrays")
-        distinct = list(dict.fromkeys(tenants))
-        # pin every already-known tenant against eviction BEFORE any
-        # cold load: an admission mid-batch must not drop another
-        # tenant whose slots ride in this same batch
-        pinned = [t for t in distinct if t in self.pool.meta]
-        for t in pinned:
-            self.pool.note_queued(t, +1)
-        try:
-            for t in distinct:
-                self.pool.ensure(t)
-                if t not in pinned:
-                    self.pool.note_queued(t, +1)
-                    pinned.append(t)
-            for i, t in enumerate(tenants):
-                self._validate(t, _OP_NAMES[int(ops[i])], int(a[i]),
-                               int(b[i]))
-            return self._dispatch_grouped(tenants, ops, a, b)
-        finally:
-            for t in pinned:
-                self.pool.note_queued(t, -1)
+        with obs.span("serve.query_batch", cat="serve") as sp:
+            ops = np.asarray(ops, dtype=np.int32)
+            a = np.asarray(a, dtype=np.int32)
+            b = (np.zeros_like(a) if b is None
+                 else np.asarray(b, dtype=np.int32))
+            tenants = list(tenants)
+            if not (len(tenants) == ops.size == a.size == b.size):
+                raise ValueError("tenants/ops/a/b must be parallel arrays")
+            distinct = list(dict.fromkeys(tenants))
+            pinned: List[str] = []
+            try:
+                with obs.span("serve.admit", cat="serve"):
+                    # pin every already-known tenant against eviction
+                    # BEFORE any cold load: an admission mid-batch must
+                    # not drop another tenant whose slots ride in this
+                    # same batch
+                    for t in distinct:
+                        if t in self.pool.meta:
+                            self.pool.note_queued(t, +1)
+                            pinned.append(t)
+                    for t in distinct:
+                        self.pool.ensure(t)
+                        if t not in pinned:
+                            self.pool.note_queued(t, +1)
+                            pinned.append(t)
+                    for i, t in enumerate(tenants):
+                        self._validate(t, _OP_NAMES[int(ops[i])], int(a[i]),
+                                       int(b[i]))
+                out = self._dispatch_grouped(tenants, ops, a, b)
+                if sp is not None:
+                    sp.update(n=len(tenants), buckets=len(
+                        {self.pool.meta[t].bucket for t in distinct}))
+                return out
+            finally:
+                for t in pinned:
+                    self.pool.note_queued(t, -1)
 
     def _dispatch_grouped(self, tenants, ops, a, b) -> np.ndarray:
-        """Group validated slots by bucket, dispatch each group in
-        fixed-size padded chunks, scatter answers back to slot order."""
+        """Group validated slots by bucket, pack each group into
+        fixed-size padded chunks, dispatch the chunks, scatter answers
+        back to slot order."""
         out = np.zeros(len(tenants), np.int32)
-        groups: Dict[BucketKey, List[int]] = {}
-        slot_of = {t: self.pool.meta[t].slot for t in set(tenants)}
-        for i, t in enumerate(tenants):
-            groups.setdefault(self.pool.meta[t].bucket, []).append(i)
-        for key, idx in groups.items():
+        chunks = []
+        with obs.span("serve.pack", cat="serve"):
+            groups: Dict[BucketKey, List[int]] = {}
+            slot_of = {t: self.pool.meta[t].slot for t in set(tenants)}
+            for i, t in enumerate(tenants):
+                groups.setdefault(self.pool.meta[t].bucket, []).append(i)
+            for key, idx in groups.items():
+                for lo in range(0, len(idx), self.batch):
+                    chunk = idx[lo:lo + self.batch]
+                    # pad with subtree_size(node 0) on tenant-slot 0 — the
+                    # root always exists for a resident tenant, and a free
+                    # slot 0 is all zeros (answer 0, masked out anyway)
+                    t_sl = np.zeros(self.batch, np.int32)
+                    op_c = np.full(self.batch, OPS["subtree_size"], np.int32)
+                    a_c = np.zeros(self.batch, np.int32)
+                    b_c = np.zeros(self.batch, np.int32)
+                    for j, i in enumerate(chunk):
+                        t_sl[j] = slot_of[tenants[i]]
+                        op_c[j] = ops[i]
+                        a_c[j] = a[i]
+                        b_c[j] = b[i]
+                    chunks.append((key, chunk, (t_sl, op_c, a_c, b_c)))
+        for key, chunk, slots in chunks:
             arrs = self.pool.bucket_arrays(key)
             J = self.buckets_J(key)
-            for lo in range(0, len(idx), self.batch):
-                chunk = idx[lo:lo + self.batch]
-                n = len(chunk)
-                # pad with subtree_size(node 0) on tenant-slot 0 — the
-                # root always exists for a resident tenant, and a free
-                # slot 0 is all zeros (answer 0, masked out anyway)
-                t_sl = np.zeros(self.batch, np.int32)
-                op_c = np.full(self.batch, OPS["subtree_size"], np.int32)
-                a_c = np.zeros(self.batch, np.int32)
-                b_c = np.zeros(self.batch, np.int32)
-                for j, i in enumerate(chunk):
-                    t_sl[j] = slot_of[tenants[i]]
-                    op_c[j] = ops[i]
-                    a_c[j] = a[i]
-                    b_c[j] = b[i]
-                t0 = time.perf_counter()
-                with obs.span("serve.dispatch", cat="serve",
-                              bucket=list(key), n=n):
-                    res = _answer_batch_multi(
-                        arrs["theta"], arrs["entity_node"],
-                        arrs["node_level"], arrs["depth"],
-                        arrs["node_size"], arrs["up"],
-                        jnp.asarray(t_sl), jnp.asarray(op_c),
-                        jnp.asarray(a_c), jnp.asarray(b_c), J,
-                    )
-                    out[chunk] = np.asarray(res)[:n]
-                self.metrics.observe("serve.dispatch_ms",
-                                     (time.perf_counter() - t0) * 1e3)
-                self.metrics.inc("serve.dispatches")
-                self.metrics.inc("serve.slots_padded", self.batch - n)
-                self.dispatches += 1
-                self.served += n
+            n = len(chunk)
+            t0 = time.perf_counter()
+            with obs.span("serve.dispatch", cat="serve") as sp:
+                res = _answer_batch_multi(
+                    arrs["theta"], arrs["entity_node"],
+                    arrs["node_level"], arrs["depth"],
+                    arrs["node_size"], arrs["up"],
+                    *(jnp.asarray(x) for x in slots), J,
+                )
+                out[chunk] = np.asarray(res)[:n]
+                if sp is not None:
+                    sp.update(bucket=list(key), n=n)
+            self.metrics.observe("serve.dispatch_ms",
+                                 (time.perf_counter() - t0) * 1e3)
+            self.metrics.inc("serve.dispatches")
+            self.metrics.inc("serve.slots_padded", self.batch - n)
+            self.dispatches += 1
+            self.served += n
         self.metrics.inc("serve.served", len(tenants))
         for t, cnt in _tenant_counts(tenants).items():
             self.metrics.inc(f"serve.tenant.{t}", cnt)

@@ -173,8 +173,9 @@ def repair_hierarchy(
                     level_block=level_block)
             labels[dirty] = fresh
 
-        h = _assemble_from_labels(
-            gg, theta, levels, labels, kind, side, prov, meta)
+        with obs.span("hierarchy.assemble", cat="hierarchy"):
+            h = _assemble_from_labels(
+                gg, theta, levels, labels, kind, side, prov, meta)
     return h, LabelCache(levels.copy(), labels, theta.copy()), n_dirty, L
 
 

@@ -15,6 +15,8 @@ The whole layer is gated by :func:`enable` / :func:`disable`.  **Off
 structural jaxpr invariant (single-``while`` FD, one-``pallas_call``
 fused body, one-psum CD, loop-free dispatch) sees the byte-identical
 program — asserted against ``tests/goldens/obs_jaxprs.json``.
+``enable(timeline=False)`` turns on spans and counters alone: no
+collector, no ring code, the same device programs as off.
 
 Set ``REPRO_OBS=1`` to enable at import time (CI trace jobs), and
 ``REPRO_OBS_RING_CAP`` to size the per-round FD rings (default 1024).
@@ -28,12 +30,13 @@ from .metrics import (Counter, Gauge, Histogram,  # noqa: F401
 from .timeline import (PeelTimeline, TimelineCollector,  # noqa: F401
                        RING_CAP_DEFAULT, fd_ring_cap, maybe_collect)
 from .timeline import active as active_collector  # noqa: F401
-from .trace import (Tracer, counter, disable, enable,  # noqa: F401
-                    enabled, get_tracer, instant, span)
+from .trace import (ANNOTATION_PREFIX, Tracer, counter,  # noqa: F401
+                    disable, enable, enabled, get_tracer, instant, span,
+                    timeline_enabled)
 
 __all__ = [
-    "Tracer", "enable", "disable", "enabled", "get_tracer",
-    "span", "instant", "counter",
+    "Tracer", "enable", "disable", "enabled", "timeline_enabled",
+    "get_tracer", "span", "instant", "counter", "ANNOTATION_PREFIX",
     "PeelTimeline", "TimelineCollector", "RING_CAP_DEFAULT",
     "fd_ring_cap", "maybe_collect", "active_collector",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "percentiles",

@@ -268,10 +268,10 @@ def active() -> Optional[TimelineCollector]:
 
 @contextmanager
 def maybe_collect() -> Iterator[Optional[TimelineCollector]]:
-    """Install a fresh collector iff the obs layer is enabled; yields
-    None (and changes nothing) otherwise."""
+    """Install a fresh collector iff the obs layer is enabled in
+    timeline mode; yields None (and changes nothing) otherwise."""
     global _collector
-    if not trace.enabled():
+    if not trace.timeline_enabled():
         yield None
         return
     prev = _collector
@@ -284,8 +284,9 @@ def maybe_collect() -> Iterator[Optional[TimelineCollector]]:
 
 def fd_ring_cap() -> int:
     """Ring capacity the FD entity wrappers should trace with: 0 unless
-    a collector is live (so the default path never sees ring code)."""
-    if _collector is None or not trace.enabled():
+    a collector is live (so the default and spans-only paths never see
+    ring code)."""
+    if _collector is None or not trace.timeline_enabled():
         return 0
     try:
         return max(int(os.environ.get("REPRO_OBS_RING_CAP",

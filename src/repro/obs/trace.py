@@ -5,14 +5,19 @@ observability layer: with it off (the default) every ``span()`` /
 ``instant()`` call returns a shared null object and the peel core picks
 a zero ring capacity, so the traced jaxprs are byte-identical to the
 uninstrumented tree (``tests/goldens/obs_jaxprs.json``).
+``enable(timeline=False)`` records spans, instants and counters but
+installs no timeline collector, so the FD ring capacity stays 0 and
+every device program is the one the layer-off path runs: host timing
+without changing what it times.
 
 With it on, a :class:`Tracer` records nested spans (Chrome-trace
 "complete" events, ``ph="X"``), instants (``ph="i"``) and counter
 samples (``ph="C"``) with categories and JSON-able args.  ``save()``
 writes the standard ``{"traceEvents": [...]}`` envelope, loadable in
 Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.  Host spans
-also enter ``jax.profiler.TraceAnnotation`` so device work lines up
-under them when a jax profile is being captured concurrently.
+also enter ``jax.profiler.TraceAnnotation`` as ``repro.<name>``, so
+device work lines up under them, on one clock, when a jax profile is
+being captured concurrently.
 
 Span taxonomy (see docs/OBSERVABILITY.md):
 
@@ -21,13 +26,22 @@ cat                   ph          meaning
 ====================  ==========  ===========================================
 ``peel``              X           one ``decompose()`` / distributed run
 ``cd``                X           Phase 1 (cover decomposition) total
+``cd.select``         X           one partition's range selection; count ==
+                                  ``p_effective``
 ``cd.round``          X           one masked peel round; count == ``rho_cd``
+``cd.step``           X           the round's ``cd_step`` (upload, update,
+                                  readback); count == ``rho_cd``
 ``fd``                X           Phase 2 (fine decomposition) total
-``fd.launch``         X           one FD dispatch (a partition, or the one
-                                  vmapped/fused launch covering all of them)
+``fd.launch``         X           one FD dispatch (``fd.partition``, or the
+                                  one ``fd.vmapped`` launch covering all)
+``fd.pack``           X           host packing + uploads before a csr
+                                  partition's launch
 ``fd.round``          i           one partition-round; count == rho_fd_total
-``hierarchy``         X           hierarchy build / save steps
-``serve``             X           pool admission + batched dispatch chunks
+                                  (timeline mode only)
+``hierarchy``         X           hierarchy build steps (labels, wedges,
+                                  assemble, node_stats)
+``serve``             X           ``query_batch`` and its admit / pack /
+                                  dispatch parts; pool admission
 ====================  ==========  ===========================================
 """
 from __future__ import annotations
@@ -44,9 +58,12 @@ except Exception:  # pragma: no cover
     _TraceAnnotation = None
 
 __all__ = [
-    "Tracer", "enable", "disable", "enabled", "get_tracer",
-    "span", "instant", "counter",
+    "Tracer", "enable", "disable", "enabled", "timeline_enabled",
+    "get_tracer", "span", "instant", "counter", "ANNOTATION_PREFIX",
 ]
+
+# a span's name in a concurrent jax profile: ``repro.<name>``
+ANNOTATION_PREFIX = "repro."
 
 
 def _jsonable(v: Any) -> Any:
@@ -96,7 +113,8 @@ class Tracer:
         a round's update delta) — merged into the event at exit."""
         t0 = self.now()
         late: Dict[str, Any] = {}
-        ann = _TraceAnnotation(name) if _TraceAnnotation is not None else None
+        ann = (_TraceAnnotation(ANNOTATION_PREFIX + name)
+               if _TraceAnnotation is not None else None)
         if ann is not None:
             ann.__enter__()
         try:
@@ -169,14 +187,21 @@ class Tracer:
 # ``is None`` check and changes no traced program.
 # ----------------------------------------------------------------------
 _tracer: Optional[Tracer] = None
+_timeline = True
 
 
-def enable() -> Tracer:
+def enable(timeline: bool = True) -> Tracer:
     """Turn the observability layer on; returns the active tracer
-    (fresh on the first call, reused afterwards)."""
-    global _tracer
+    (fresh on the first call, reused afterwards).
+
+    ``timeline=False`` is the spans-only mode: spans, instants and
+    counters record, but no timeline collector is installed, so no FD
+    counter-ring program is traced and no ``fd.round`` instants are
+    synthesised.  Every device program is then the layer-off one."""
+    global _tracer, _timeline
     if _tracer is None:
         _tracer = Tracer()
+    _timeline = bool(timeline)
     return _tracer
 
 
@@ -189,6 +214,11 @@ def disable() -> None:
 def enabled() -> bool:
     """Whether the observability layer is on."""
     return _tracer is not None
+
+
+def timeline_enabled() -> bool:
+    """Whether the layer is on with its per-round timeline collector."""
+    return _tracer is not None and _timeline
 
 
 def get_tracer() -> Optional[Tracer]:

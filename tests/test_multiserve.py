@@ -222,6 +222,51 @@ def test_admission_cannot_evict_tenant_of_same_batch(tenant_dir):
     assert all(m.queued == 0 for m in pool.meta.values())  # pins released
 
 
+def test_serve_spans_one_per_call_and_per_chunk(tenant_dir):
+    """Spans-only obs: one ``serve.query_batch`` (with admit and pack)
+    per call, one ``serve.dispatch`` per dispatched chunk, and the same
+    answers as with obs off."""
+    from repro import obs
+
+    pool = ForestPool(slots=8, artifact_dir=tenant_dir)
+    svc = MultiTenantService(pool, batch=32)
+    calls = [_workload_all(pool, ["big0", "big1", "small0"], n=n, seed=n)
+             for n in (100, 7)]
+    want = [svc.query_batch(*c) for c in calls]
+    d0 = svc.dispatches
+    obs.disable()
+    t = obs.enable(timeline=False)
+    try:
+        got = [svc.query_batch(*c) for c in calls]
+    finally:
+        obs.disable()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    by_name = {}
+    for e in t.spans("serve"):
+        by_name.setdefault(e["name"], []).append(e)
+    qb = by_name["serve.query_batch"]
+    assert len(qb) == len(calls)
+    assert [e["args"]["n"] for e in qb] == [len(c[0]) for c in calls]
+    assert [e["args"]["buckets"] for e in qb] == [2, 2]
+    assert len(by_name["serve.admit"]) == len(calls)
+    assert len(by_name["serve.pack"]) == len(calls)
+    disp = by_name["serve.dispatch"]
+    chunks = 0
+    for tenants, *_ in calls:
+        per_bucket = {}
+        for x in tenants:
+            key = pool.meta[x].bucket
+            per_bucket[key] = per_bucket.get(key, 0) + 1
+        chunks += sum(-(-c // svc.batch) for c in per_bucket.values())
+    assert len(disp) == svc.dispatches - d0 == chunks
+    assert sum(e["args"]["n"] for e in disp) == 107
+    for q in qb:                      # every dispatch inside its call
+        inner = [e for e in disp
+                 if q["ts"] <= e["ts"] <= q["ts"] + q["dur"]]
+        assert sum(e["args"]["n"] for e in inner) == q["args"]["n"]
+
+
 # --------------------------------------------------- artifact versions
 def test_v1_artifact_loads_through_loader_branch(tenant_dir, tmp_path):
     """Old-format artifacts written before the pack cache existed must

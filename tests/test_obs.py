@@ -8,7 +8,10 @@ Four claims under test:
   span counts equal the run's :class:`PeelStats` *exactly*
   (``cd.round`` count == ``rho_cd``, ``fd.round`` count ==
   ``rho_fd_total``), across engines and FD drivers, single-node and
-  distributed; and enabling telemetry never changes θ.
+  distributed; and enabling telemetry never changes θ.  In spans-only
+  mode (``enable(timeline=False)``) the same holds for ``cd.round`` /
+  ``cd.step`` / ``cd.select`` / ``fd.partition`` / ``hierarchy.assemble``,
+  with no timeline and no ``*_rings`` FD program called.
 * **Serving metrics oracle** — pool cache counters mirror the pool's
   plain-int LRU bookkeeping one-for-one; per-slot admission upload is
   bit-identical to the whole-bucket re-upload it replaces.
@@ -34,6 +37,8 @@ import numpy as np
 import pytest
 
 from repro import obs
+from repro.core import peel as peel_mod
+from repro.core import peelspec
 from repro.core.graph import powerlaw_bipartite, random_bipartite
 from repro.core.peel import tip_decomposition, wing_decomposition
 from repro.hierarchy import (
@@ -242,20 +247,157 @@ def _assert_exact_match(run):
     assert res.provenance()["timeline"]["cd_rounds"] == st.rho_cd
 
 
-@pytest.mark.parametrize("engine,fd_driver,fused", WING_COMBOS)
-def test_wing_trace_counts_match_stats(engine, fd_driver, fused):
-    g = random_bipartite(30, 24, 140, seed=1)
-    _assert_exact_match(
-        lambda: wing_decomposition(g, P=4, engine=engine,
-                                   fd_driver=fd_driver, fused=fused))
+# every telemetry-on FD entry: spans-only mode must call none of them
+RING_ENTRIES = [
+    (peel_mod, name) for name in (
+        "_fd_tip_vmapped_rings", "_fd_wing_vmapped_rings",
+        "_fd_wing_vmapped_pallas_rings", "_fd_wing_fused_rings",
+        "_fd_tip_fused_rings", "_fd_tip_device_rings",
+        "_fd_wing_device_rings")
+] + [
+    (peelspec, name) for name in (
+        "_fd_while_device_rings", "_fd_while_vmapped_rings",
+        "_fd_while_fused_rings")
+]
 
 
-@pytest.mark.parametrize("engine,fd_driver,fused", TIP_COMBOS)
-def test_tip_trace_counts_match_stats(engine, fd_driver, fused):
+def _refuse_rings(monkeypatch):
+    for mod, name in RING_ENTRIES:
+        def refuse(*a, _name=name, **kw):
+            raise AssertionError(f"{_name} called in spans-only mode")
+
+        monkeypatch.setattr(mod, name, refuse)
+
+
+def _assert_spans_only(run, build, fd_driver, monkeypatch):
+    """Spans-only mode (``enable(timeline=False)``): θ == the obs-off
+    run, no timeline, no ring program, and the CD / FD / build span
+    counts equal PeelStats."""
+    obs.disable()
+    base = run()
+    _refuse_rings(monkeypatch)
+    t = obs.enable(timeline=False)
+    try:
+        assert obs.enabled() and not obs.timeline_enabled()
+        res = run()
+        build(res)
+    finally:
+        obs.disable()
+    np.testing.assert_array_equal(res.theta, base.theta)
+    assert res.timeline is None
+    assert "timeline" not in res.provenance()
+    st = res.stats
+    assert t.count("cd.round", ph="X") == st.rho_cd
+    assert t.count("cd.step", ph="X") == st.rho_cd
+    assert t.count("cd.select", ph="X") == st.p_effective
+    assert t.count("fd.round", ph="i") == 0
+    n_part = sum(e["name"] == "fd.partition" for e in t.spans("fd.launch"))
+    if fd_driver == "vmapped":
+        assert n_part == 0
+        assert t.count("fd.launch") == 1
+    else:
+        assert n_part == st.p_effective
+        parts = sorted(e["args"]["part"] for e in t.spans("fd.launch"))
+        assert parts == list(range(st.p_effective))
+        assert t.sum_arg("rounds", cat="fd.launch") == st.rho_fd_total
+    select = t.spans("cd.select")
+    assert [e["args"]["part"] for e in select] == list(range(st.p_effective))
+    assert [e["args"]["hi"] for e in select] == res.ranges[1:].tolist()
+    assert t.sum_arg("died", cat="cd.step") == res.theta.size
+    assert t.count("peel", ph="X") == 1
+    assert sum(e["name"] == "hierarchy.assemble"
+               for e in t.spans("hierarchy")) == 1
+    assert sum(e["name"] == "hierarchy.build"
+               for e in t.spans("hierarchy")) == 1
+
+
+def test_spans_enter_the_jax_profile_as_repro(tmp_path):
+    """Each span is a ``repro.<name>`` annotation in a concurrent jax
+    profile, on the profiler's clock beside the device work."""
+    import gzip
+    import glob
+
+    import jax
+
+    obs.disable()
+    obs.enable(timeline=False)
+    try:
+        jax.profiler.start_trace(str(tmp_path), create_perfetto_trace=True)
+        try:
+            with obs.span("cd.step", cat="cd.step"):
+                jax.block_until_ready(jax.numpy.arange(8) + 1)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.disable()
+    (path,) = glob.glob(str(tmp_path / "**" / "perfetto_trace.json.gz"),
+                        recursive=True)
+    with gzip.open(path, "rt") as fh:
+        names = {e.get("name") for e in json.load(fh)["traceEvents"]}
+    assert obs.ANNOTATION_PREFIX + "cd.step" in names
+    assert "cd.step" not in names
+
+
+def test_spans_only_mode_installs_no_collector():
+    obs.disable()
+    obs.enable(timeline=False)
+    try:
+        with obs.maybe_collect() as col:
+            assert col is None
+            assert obs.active_collector() is None
+            assert obs.fd_ring_cap() == 0
+        obs.enable()                     # back to timeline mode
+        assert obs.timeline_enabled()
+        with obs.maybe_collect() as col:
+            assert col is not None
+            assert obs.fd_ring_cap() == obs.RING_CAP_DEFAULT
+    finally:
+        obs.disable()
+    assert not obs.timeline_enabled()
+
+
+def _modes(combos):
+    """Each combo in timeline mode (under its plain id) and in
+    spans-only mode."""
+    return ([pytest.param(*c, True, id="-".join(map(str, c)))
+             for c in combos]
+            + [pytest.param(*c, False, id="-".join(map(str, c)) + "-spans")
+               for c in combos])
+
+
+@pytest.mark.parametrize("engine,fd_driver,fused,timeline",
+                         _modes(WING_COMBOS))
+def test_wing_trace_counts_match_stats(engine, fd_driver, fused, timeline,
+                                       monkeypatch):
     g = random_bipartite(30, 24, 140, seed=1)
-    _assert_exact_match(
-        lambda: tip_decomposition(g, side="u", P=4, engine=engine,
-                                  fd_driver=fd_driver, fused=fused))
+
+    def run():
+        return wing_decomposition(g, P=4, engine=engine,
+                                  fd_driver=fd_driver, fused=fused)
+
+    if timeline:
+        _assert_exact_match(run)
+    else:
+        _assert_spans_only(run, lambda res: build_hierarchy(g, res),
+                           fd_driver, monkeypatch)
+
+
+@pytest.mark.parametrize("engine,fd_driver,fused,timeline",
+                         _modes(TIP_COMBOS))
+def test_tip_trace_counts_match_stats(engine, fd_driver, fused, timeline,
+                                      monkeypatch):
+    g = random_bipartite(30, 24, 140, seed=1)
+
+    def run():
+        return tip_decomposition(g, side="u", P=4, engine=engine,
+                                 fd_driver=fd_driver, fused=fused)
+
+    if timeline:
+        _assert_exact_match(run)
+    else:
+        _assert_spans_only(
+            run, lambda res: build_hierarchy(g, res, kind="tip", side="u"),
+            fd_driver, monkeypatch)
 
 
 def test_distributed_trace_counts_match_stats():
