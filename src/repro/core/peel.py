@@ -509,6 +509,103 @@ def _fd_wing_device_rings(mine, sup0, alive_w0, W0, we1, we2, wp,
         mine, sup0, update, (alive_w0, W0), ring_cap)
 
 
+# Smallest wedge-list size the compacting wing FD driver shrinks to.
+# Below it a relaunch (a scalar readback, a compaction and a fresh loop)
+# costs more than the dead wedge slots it stops re-reading.
+_FD_COMPACT_FLOOR = 4096
+
+
+def _wedge_shrink_limit(size: int) -> int:
+    """Most live wedges at which a wedge list of ``size`` slots moves to
+    a smaller power-of-two size: the largest power of two below
+    ``size``, or -1 where ``size`` is at the floor and never shrinks."""
+    if size <= _FD_COMPACT_FLOOR:
+        return -1
+    return 1 << ((size - 1).bit_length() - 1)
+
+
+def _wedge_shrink_size(live: int) -> int:
+    """The power-of-two size ``live`` wedges move to (at least the
+    floor)."""
+    return max(1 << max(live - 1, 0).bit_length(), _FD_COMPACT_FLOOR)
+
+
+@partial(jax.jit, static_argnames=("n_pairs", "m"))
+def _fd_wing_chunk(state, we1, we2, wp, n_pairs: int, m: int):
+    """Rounds of :func:`_fd_wing_device` on its loop carry ``state``
+    until the partition drains or its live wedges fit a smaller size
+    (:func:`_wedge_shrink_limit` of the wedge arrays' length).
+
+    Returns ``(state', left)``: ``left`` is the live-wedge count, or -1
+    once the partition has drained."""
+    limit = _wedge_shrink_limit(we1.shape[0])
+    update = _wing_device_update(we1, we2, wp, n_pairs, m)
+
+    def n_live(st):
+        alive_w, _ = st[2]
+        return jnp.sum(alive_w.astype(jnp.int32))
+
+    def cond(carry):
+        st, live = carry
+        return jnp.any(st[0]) & (live > limit)
+
+    def body(carry):
+        st = peelspec._fd_round(carry[0], update)
+        return st, n_live(st)
+
+    state, live = jax.lax.while_loop(cond, body, (state, n_live(state)))
+    return state, jnp.where(jnp.any(state[0]), live, -1)
+
+
+@partial(jax.jit, static_argnames=("size",))
+def _compact_wedges(alive_w, we1, we2, wp, size: int):
+    """The live wedges packed in order to the front of ``size`` slots;
+    the slots after them are dead zero wedges, which are inert."""
+    dest = jnp.where(alive_w, jnp.cumsum(alive_w, dtype=jnp.int32) - 1, size)
+
+    def pack(x):
+        return jnp.zeros((size,), x.dtype).at[dest].set(x, mode="drop")
+
+    return pack(alive_w), pack(we1), pack(we2), pack(wp)
+
+
+def _fd_wing_compacting(mine, sup0, alive_w0, W0, we1, we2, wp,
+                        n_pairs: int, m: int, part: int = 0):
+    """:func:`_fd_wing_device`'s cascade (same arguments and results,
+    bit-identical) with the wedge list shrunk as its wedges die.
+
+    Each launch of :func:`_fd_wing_chunk` runs until the partition
+    drains or its live wedges fit a smaller power-of-two size; the host
+    reads that count, packs the live wedges into arrays of the smaller
+    size (:func:`_compact_wedges`) and relaunches there, the edge-sized
+    state staying on the device.  A dead wedge adds nothing to any loss
+    or pair count, so dropping it changes no result; later rounds just
+    stop re-reading it.  Each compaction plus relaunch is an
+    ``fd.compact`` span (args ``part``, ``live``, ``size_from``,
+    ``size_to``); ``part`` only labels those spans."""
+    zero = jnp.int32(0)
+    state = (mine, sup0, (alive_w0, W0), jnp.zeros_like(sup0), zero, zero,
+             zero)
+    state, left = _fd_wing_chunk(state, we1, we2, wp, n_pairs=n_pairs, m=m)
+    size = we1.shape[0]
+    while _wedge_shrink_limit(size) >= 0:
+        live = int(left)
+        if live < 0:
+            break
+        new = _wedge_shrink_size(live)
+        with obs.span("fd.compact", cat="fd.compact", part=int(part),
+                      live=live, size_from=size, size_to=new):
+            alive, sup, (alive_w, W), *rest = state
+            alive_w, we1, we2, wp = _compact_wedges(
+                alive_w, we1, we2, wp, size=new)
+            state, left = _fd_wing_chunk(
+                (alive, sup, (alive_w, W), *rest), we1, we2, wp,
+                n_pairs=n_pairs, m=m)
+        size = new
+    _, _, _, theta, _, rounds, nupd = state
+    return theta, rounds, nupd
+
+
 def _drain_rings(mode, parts, rounds, rings, cap, cumulative=False):
     """Hand one FD launch's counter rings to the active timeline
     collector (no-op when the obs layer is off)."""
@@ -1447,8 +1544,10 @@ def _wing_fd_csr(
     their survivor charges land on edges whose deltas are discarded
     anyway (their FD runs from its own ⋈init snapshot).
 
-    ``fd_driver="device"`` (default) runs the whole cascade in one
-    ``lax.while_loop`` (:func:`_fd_wing_device`); ``"host"`` keeps the
+    ``fd_driver="device"`` (default) runs the whole cascade in
+    ``lax.while_loop`` launches that shrink the wedge list as its wedges
+    die (:func:`_fd_wing_compacting`; the obs timeline keeps the single
+    launch of :func:`_fd_wing_device_rings`); ``"host"`` keeps the
     per-round dispatch loop (the PR-1 baseline, benchmarked against).
     """
     with obs.span("fd.pack", cat="fd.pack"):
@@ -1506,7 +1605,7 @@ def _wing_fd_csr(
                 *args, ring_cap=cap)
             _drain_rings("device", [i], [int(rounds)], rings, cap)
         else:
-            theta_d, rounds, nupd = _fd_wing_device(*args)
+            theta_d, rounds, nupd = _fd_wing_compacting(*args, part=i)
         theta_np = np.asarray(theta_d).astype(np.int64)
         theta[mine] = theta_np[mine]
         return int(rounds), int(nupd)

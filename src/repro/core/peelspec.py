@@ -29,6 +29,7 @@ against the pre-refactor engines in ``tests/test_peelspec_goldens.py``).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -532,27 +533,32 @@ def _fd_while_device(mine: jax.Array, sup0: jax.Array, update, aux):
         alive, *_ = state
         return jnp.any(alive)
 
-    def body(state):
-        alive, sup, aux, theta, k, rounds, nupd = state
-        cur = jnp.where(alive, sup, _FD_BIG)
-        k = jnp.maximum(k, jnp.min(cur))
-        S = alive & (sup <= k)
-        # S is non-empty whenever alive is (k ≥ min alive support), so
-        # every iteration is one real peel round — same count as the
-        # host cascade.
-        theta = jnp.where(S, k, theta)
-        alive = alive & ~S
-        loss, aux, nu = update(S, aux)
-        return (alive, sup - loss, aux, theta, k, rounds + 1, nupd + nu)
-
     # derive loop-constant inits from varying inputs so the carry's
     # manual-axes annotation is stable under shard_map (same trick as
     # distributed._fd_body_one_partition)
     zero_e = sup0 * 0
     zero_s = jnp.min(zero_e)
     init = (mine, sup0, aux, zero_e, zero_s, zero_s, zero_s)
-    _, _, _, theta, _, rounds, nupd = jax.lax.while_loop(cond, body, init)
+    _, _, _, theta, _, rounds, nupd = jax.lax.while_loop(
+        cond, partial(_fd_round, update=update), init)
     return theta, rounds, nupd
+
+
+def _fd_round(state, update):
+    """One peel round of :func:`_fd_while_device` on its loop carry
+    ``(alive, sup, aux, theta, k, rounds, nupd)`` — also the round of the
+    wing driver that relaunches at shrinking wedge sizes
+    (``peel._fd_wing_chunk``)."""
+    alive, sup, aux, theta, k, rounds, nupd = state
+    cur = jnp.where(alive, sup, _FD_BIG)
+    k = jnp.maximum(k, jnp.min(cur))
+    S = alive & (sup <= k)
+    # S is non-empty whenever alive is (k ≥ min alive support), so every
+    # iteration is one real peel round — same count as the host cascade.
+    theta = jnp.where(S, k, theta)
+    alive = alive & ~S
+    loss, aux, nu = update(S, aux)
+    return (alive, sup - loss, aux, theta, k, rounds + 1, nupd + nu)
 
 
 def _fd_while_vmapped(mine: jax.Array, sup0: jax.Array, update, aux):

@@ -163,9 +163,10 @@ def vmapped_tip_jaxpr() -> str:
 
 def device_wing_jaxpr() -> str:
     """Per-partition wing FD while_loop on a fixed synthetic shape —
-    the program streaming's localized re-runs (``run_fd(only=...)``)
-    dispatch per dirty partition.  A jaxpr is a function of shapes and
-    statics only, so no graph artifacts are needed."""
+    the single launch that the compacting driver behind ``run_fd``
+    (streaming's ``only=...`` re-runs included) must match, and that the
+    timeline twin mirrors.  A jaxpr is a function of shapes and statics
+    only, so no graph artifacts are needed."""
     import jax
     import jax.numpy as jnp
 

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.peel import _fd_tip_device, _fd_wing_device
+from repro.core.peel import (
+    _FD_COMPACT_FLOOR, _fd_tip_device, _fd_wing_chunk, _fd_wing_device)
 from repro.kernels import ops as kops
 
 # phase-(b) widths: W0 max 169 wedges per pair → 256 slot lanes (wing);
@@ -108,6 +109,22 @@ def test_wing_fd_while_compiles_for_v5e(one_chip):
         _s(s, (WEDGE_BUCKET,), bool), _s(s, (N_PAIRS,), jnp.int32),
         _s(s, (WEDGE_BUCKET,), jnp.int32), _s(s, (WEDGE_BUCKET,), jnp.int32),
         _s(s, (WEDGE_BUCKET,), jnp.int32), n_pairs=N_PAIRS, m=M))
+    assert "while" in hlo
+
+
+@pytest.mark.parametrize("size", [WEDGE_BUCKET, _FD_COMPACT_FLOOR])
+def test_wing_fd_chunk_compiles_for_v5e(one_chip, size):
+    """The compacting wing FD's loop at the largest bucket and at the
+    ladder's floor, where it runs until the partition drains."""
+    s = one_chip
+    i32 = jnp.int32
+    state = (_s(s, (M,), bool), _s(s, (M,), i32),
+             (_s(s, (size,), bool), _s(s, (N_PAIRS,), i32)),
+             _s(s, (M,), i32), _s(s, (), i32), _s(s, (), i32),
+             _s(s, (), i32))
+    hlo = _compile(_fd_wing_chunk.lower(
+        state, _s(s, (size,), i32), _s(s, (size,), i32),
+        _s(s, (size,), i32), n_pairs=N_PAIRS, m=M))
     assert "while" in hlo
 
 
