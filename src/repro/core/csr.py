@@ -26,11 +26,17 @@ Pipeline:
      kernel in ``repro.kernels.wedge_count`` over a :class:`PaddedCSR`
      pairs-major slot matrix (MXU/VMEM tiling; interpret mode on CPU).
 
-Everything is exact integer arithmetic (int32 on device) — no f32
-rounding, so θ from the csr engine is bit-identical to the BUP oracle.
+Everything is exact integer arithmetic — int32 on device, int64 where a
+tip graph's counts exceed int32 (:func:`count_bits`, :func:`count_scope`)
+— no f32 rounding, so θ from the csr engine is bit-identical to the BUP
+oracle.  Tip peeling reads only the U pairs with a common neighbour and
+their counts (:class:`TipPairs`); where it pays, one int8 adjacency
+product on the MXU gives them instead of the wedge list
+(:func:`tip_pairs`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Optional, Tuple
@@ -43,9 +49,16 @@ from .graph import BipartiteGraph
 
 __all__ = [
     "Wedges",
+    "TipPairs",
     "PaddedCSR",
     "TileStats",
     "build_wedges",
+    "tip_pairs",
+    "tip_pair_adjacency",
+    "tip_pair_counts",
+    "pairs_from_product",
+    "count_bits",
+    "count_scope",
     "iter_wedge_tiles",
     "tiled_butterfly_init",
     "pad_segments",
@@ -97,12 +110,44 @@ class Wedges:
         return int(self.wedge_pair.shape[0])
 
     def pair_butterflies0(self) -> np.ndarray:
-        """Static C(W0, 2) per pair (V side never peeled ⇒ valid for tip)."""
+        """Static C(W0, 2) per pair (V side never peeled ⇒ valid for tip),
+        exact int64."""
         w = self.W0
-        bf = w * (w - 1) // 2
-        if bf.size and int(bf.max()) > _INT_LIMIT:
-            raise OverflowError("pair butterfly counts exceed int32 range")
-        return bf
+        return w * (w - 1) // 2
+
+
+@dataclasses.dataclass(frozen=True)
+class TipPairs:
+    """Every pair of U vertices with a common V neighbour, and how many
+    they share: all tip peeling reads of the graph.
+
+    ``source`` says how the list was made: ``"wedges"`` from the host
+    wedge list (:func:`build_wedges`), ``"mxu"`` from one adjacency
+    product on the device (:func:`pairs_from_product`), whose shape as
+    it ran, padding included, is ``rows`` × ``k`` of ``dtype``.  Pairs
+    are ordered by (pair_a, pair_b) either way, so both sources give
+    the same arrays."""
+
+    n_u: int
+    pair_a: np.ndarray      # (n_pairs,) int32 — smaller U endpoint
+    pair_b: np.ndarray      # (n_pairs,) int32 — larger U endpoint
+    W0: np.ndarray          # (n_pairs,) int64 — common neighbours
+    source: str = "wedges"
+    rows: int = 0
+    k: int = 0
+    dtype: str = ""
+
+    @property
+    def n_pairs(self) -> int:
+        """Number of pairs with a common neighbour."""
+        return int(self.pair_a.shape[0])
+
+    pair_butterflies0 = Wedges.pair_butterflies0
+
+    @classmethod
+    def of(cls, w: Wedges) -> "TipPairs":
+        """The pair part of a wedge list."""
+        return cls(w.n_u, w.pair_a, w.pair_b, w.W0)
 
 
 def build_wedges(g: BipartiteGraph) -> Wedges:
@@ -145,6 +190,118 @@ def build_wedges(g: BipartiteGraph) -> Wedges:
         wedge_e2=eid[e2_pos].astype(np.int32),
         W0=np.bincount(wedge_pair, minlength=pair_key.size).astype(np.int64),
     )
+
+
+# =====================================================================
+# Tip pair counts: the host wedge list or one MXU product
+# =====================================================================
+# The product replaces the wedge enumeration where it fits and costs
+# less.  It fits where its int8 operand and int32 result stay under
+# 512 MiB and 256 MiB (the operand bound also keeps its padded flat
+# indices below 2^31).  Its cost is a fixed part (two launches, the
+# index upload, the readback's set-up), rows² counts read back and
+# scanned on the host, and rows²·k multiply-adds on the device; the
+# host's is one pass per wedge.  In ns: the host rates timed on an x86
+# host, the multiply-add the v5e compiler's estimate for the hub
+# product, 755,548 cycles at 1.5 GHz for 512²·2^18 (PERF.md §6).
+_MXU_OPERAND_BYTES = 2 ** 29
+_MXU_RESULT_BYTES = 2 ** 28
+_NS_FIXED = 2e6
+_NS_PER_COUNT = 8.0
+_NS_PER_MAC = 0.0073
+_NS_PER_WEDGE = 100.0
+
+
+def _product_shape(n_u: int, n_v: int) -> Tuple[int, int]:
+    """(rows, k) of the padded U × V adjacency the product reads: rows
+    a multiple of 128, k a quarter-power-of-two bucket (≤ 25 % padding),
+    so graphs of similar size share one compiled program."""
+    from .peelspec import _bucket_pad
+
+    return -(-max(n_u, 1) // 128) * 128, _bucket_pad(max(n_v, 1))
+
+
+def pair_source(n_u: int, n_v: int, wedges: int) -> str:
+    """``"mxu"`` or ``"wedges"``: how :func:`tip_pairs` builds the pair
+    list of a graph with ``n_u`` peeled vertices, ``n_v`` on the other
+    side and ``wedges`` = Σ_v C(d_v, 2) (the rule above)."""
+    rows, k = _product_shape(n_u, n_v)
+    fits = (rows * k <= _MXU_OPERAND_BYTES
+            and rows * rows * 4 <= _MXU_RESULT_BYTES)
+    cost = _NS_FIXED + rows * rows * (_NS_PER_COUNT + k * _NS_PER_MAC)
+    return "mxu" if fits and cost <= wedges * _NS_PER_WEDGE else "wedges"
+
+
+@partial(jax.jit, static_argnames=("rows", "k"))
+def tip_pair_adjacency(flat: jax.Array, rows: int, k: int):
+    """The (rows, k) int8 0/1 adjacency the product reads: the edges, as
+    sorted distinct flat indices u·k + v (padding past rows·k, dropped),
+    set in zeros.  The scatter takes its indices with their order
+    declared, so the compiler adds no sort and every operation it emits
+    carries this program's name."""
+    return jnp.zeros((rows * k,), jnp.int8).at[flat].set(
+        jnp.int8(1), mode="drop", indices_are_sorted=True,
+        unique_indices=True).reshape(rows, k)
+
+
+@jax.jit
+def tip_pair_counts(adj: jax.Array):
+    """Common neighbours of every pair of U rows: the int8 adjacency
+    times its transpose on the MXU with int32 accumulation, and nothing
+    else, so the device trace names the product alone.  Exact: an entry
+    counts at most k neighbours, and :func:`pair_source` keeps
+    rows·k < 2^31."""
+    return jax.lax.dot_general(
+        adj, adj, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)
+
+
+def pairs_from_product(g: BipartiteGraph) -> TipPairs:
+    """The pair list of g from two programs on the device,
+    :func:`tip_pair_adjacency` over its edges and :func:`tip_pair_counts`
+    over that; the host keeps the upper triangle's non-zero entries, in
+    (pair_a, pair_b) order as :func:`build_wedges` gives them."""
+    from .peelspec import _bucket_pad
+
+    rows, k = _product_shape(g.n_u, g.n_v)
+    flat = g.edges[:, 0].astype(np.int64) * k + g.edges[:, 1]
+    if not (np.diff(flat) > 0).all():      # the scatter's declared order
+        flat = np.unique(flat)
+    idx = np.arange(rows * k, rows * k + _bucket_pad(flat.size),
+                    dtype=np.int64)
+    idx[:flat.size] = flat
+    adj = tip_pair_adjacency(jnp.asarray(idx.astype(np.int32)),
+                             rows=rows, k=k)
+    c = np.asarray(tip_pair_counts(adj))[:g.n_u, :g.n_u]
+    a, b = np.nonzero(np.triu(c, 1))
+    return TipPairs(g.n_u, a.astype(np.int32), b.astype(np.int32),
+                    c[a, b].astype(np.int64), source="mxu", rows=rows, k=k,
+                    dtype="int8")
+
+
+def tip_pairs(g: BipartiteGraph) -> TipPairs:
+    """The pair list tip peeling of g's U side reads, from the source
+    :func:`pair_source` picks out of the degrees."""
+    _, dv = g.degrees()
+    wedges = int((dv * (dv - 1) // 2).sum())
+    if pair_source(g.n_u, g.n_v, wedges) == "mxu":
+        return pairs_from_product(g)
+    return TipPairs.of(build_wedges(g))
+
+
+def count_bits(*counts: np.ndarray) -> int:
+    """32, or 64 where any of the int64 ``counts`` exceeds int32: the
+    device width the peel's supports, deltas and θ need.  Supports only
+    fall during peeling, so their initial values decide."""
+    top = max((int(c.max()) for c in counts if c.size), default=0)
+    return 64 if top > _INT_LIMIT else 32
+
+
+def count_scope(bits: int):
+    """Where device counts of ``bits`` are made and used: JAX's scoped
+    64-bit mode for 64 (its programs are traced and cached apart), no
+    change for 32, so the int32 programs stay as they are."""
+    return jax.enable_x64(True) if bits == 64 else contextlib.nullcontext()
 
 
 # =====================================================================

@@ -355,7 +355,8 @@ def shard_wedges(wed: csr.Wedges, n_dev: int) -> ShardedCSRState:
 
     sup0 = csr.edge_butterflies0(wed)
     if sup0.size and int(sup0.max()) > 2 ** 31 - 1:
-        raise OverflowError("wing supports exceed int32; shard the graph")
+        raise OverflowError("wing supports exceed int32, the width of the "
+                            "wing path's device counts")
     W_pad = np.zeros(n_pairs + 1, dtype=np.int32)
     W_pad[:n_pairs] = wed.W0.astype(np.int32)
     return ShardedCSRState(
@@ -937,9 +938,9 @@ def _pack_fd_slots_csr(per: list, n_parts: int, Emax: int,
 
 
 def pack_fd_partitions_tip_csr(
-    wed: csr.Wedges, pair_bf0: np.ndarray, part: np.ndarray,
+    wed: csr.TipPairs, pair_bf0: np.ndarray, part: np.ndarray,
     sup_init: np.ndarray, n_parts: int, bucket: bool = False,
-    stacked: bool = False,
+    stacked: bool = False, dtype=np.int32,
 ) -> dict:
     """Tip counterpart of :func:`pack_fd_partitions_csr`.
 
@@ -960,7 +961,9 @@ def pack_fd_partitions_tip_csr(
     ``stacked=True`` additionally emits the [n_parts, Lmax] blocks
     ``st_pa``/``st_pb``/``st_bf`` (partition-LOCAL vertex ids, bf=0 on
     padding) the per-partition shard_map FD
-    (:func:`fd_peel_sharded_tip_csr`) consumes."""
+    (:func:`fd_peel_sharded_tip_csr`) consumes.  ``dtype`` is the count
+    width of ``bf`` and ``sup0`` (int64 where ``csr.count_bits`` asks
+    for it); the stacked blocks feed int32 kernels and stay int32."""
     n = part.size
     pa_p = part[wed.pair_a] if wed.n_pairs else np.zeros(0, np.int32)
     pb_p = part[wed.pair_b] if wed.n_pairs else np.zeros(0, np.int32)
@@ -973,7 +976,7 @@ def pack_fd_partitions_tip_csr(
         per.append(dict(
             nodes=mine_idx,
             pa=loc[wed.pair_a[keep]], pb=loc[wed.pair_b[keep]],
-            bf=pair_bf0[keep].astype(np.int32),
+            bf=pair_bf0[keep].astype(dtype),
             sup0=sup_init[mine_idx],
         ))
     Emax = max((p["nodes"].size for p in per), default=1) or 1
@@ -986,9 +989,9 @@ def pack_fd_partitions_tip_csr(
         Wpad = _bucket_pad(Wpad)
     pa = np.zeros(Wpad, dtype=np.int32)
     pb = np.zeros(Wpad, dtype=np.int32)
-    bf = np.zeros(Wpad, dtype=np.int32)
+    bf = np.zeros(Wpad, dtype=dtype)
     mine = np.zeros((n_parts, Emax), dtype=bool)
-    sup0 = np.zeros((n_parts, Emax), dtype=np.int32)
+    sup0 = np.zeros((n_parts, Emax), dtype=dtype)
     gids = np.zeros((n_parts, Emax), dtype=np.int32)
     pos = 0
     for i, p in enumerate(per):
@@ -1303,7 +1306,8 @@ def _distributed_wing_csr(
         sup0 = csr.edge_butterflies0(wed)
         if sup0.size and int(sup0.max()) > 2 ** 31 - 1:
             raise OverflowError(
-                "wing supports exceed int32; shard the graph")
+                "wing supports exceed int32, the width of the wing "
+                "path's device counts")
         support = jnp.asarray(sup0.astype(np.int32))
         st = None
     else:
@@ -1484,7 +1488,9 @@ def _distributed_tip_csr(
     pair_bf0 = wed.pair_butterflies0()
     sup0 = csr.vertex_butterflies_csr(wed)
     if sup0.size and int(sup0.max()) > 2 ** 31 - 1:
-        raise OverflowError("tip supports exceed int32; shard the graph")
+        raise OverflowError(
+            "tip supports exceed int32, the width of the sharded tip "
+            "path's counts; the single-device csr engine widens them")
     wu, _ = csr.wedge_workload(gg)
     wedge_w = wu.astype(np.float64)
 

@@ -50,7 +50,6 @@ from .peelspec import (  # noqa: F401 — canonical home is peelspec; kept
     PeelSpec,
     PeelStats,
     AdaptiveTarget as _AdaptiveTarget,
-    _FD_BIG,
     _bucket_pad,
     _fd_cascade,
     _fd_while_device,
@@ -667,7 +666,8 @@ def tip_decomposition(
     engine    support counting / update              fd_driver
     ========  =====================================  ====================
     dense     masked MXU matmul re-counts, O(n²)     (host cascade)
-    csr       incremental pair updates, O(Σ deg²)    device │ vmapped │ host
+    csr       incremental pair updates on the pair   device │ vmapped │ host
+              list (MXU product or wedge list)
     ========  =====================================  ====================
 
     Example::
@@ -678,9 +678,26 @@ def tip_decomposition(
         print(res.theta.max(), res.stats.rho_cd)
 
     ``engine="dense"`` (default) re-counts with masked MXU matmuls;
-    ``engine="csr"`` peels on the sparse wedge list (``core.csr``) with
-    purely incremental pair updates — O(Σ deg²) memory, the only option
-    once the n×n wedge matrix stops fitting.
+    ``engine="csr"`` peels on the U pair list (``core.csr``) with purely
+    incremental pair updates, the only option once the n×n wedge matrix
+    stops fitting.  The csr engine chooses from the graph, with no flag:
+
+    * **the pair source** (``csr.pair_source``, from n = |peeled side|,
+      the other side's size k and W = Σ C(d, 2) over it): one int8
+      adjacency product on the MXU (``csr.tip_pair_counts``, exact int32
+      accumulation) where its operand and result fit 512 MiB and
+      256 MiB and its cost, 2 ms + n²·(8 ns + k·0.0073 ns), is at most
+      the host wedge list's W·100 ns (host rates timed on an x86 host,
+      the multiply-add the v5e compiler's estimate); the host wedge
+      list otherwise;
+    * **the count width** (``csr.count_bits``, from the largest ⋈init
+      and pair count): int32, or int64 for supports, deltas, the FD
+      loop's state and θ, under JAX's scoped 64-bit mode
+      (``csr.count_scope``) so the int32 programs stay as they are.
+
+    ``stats`` reports both (``pair_source``, ``pair_rows``, ``pair_k``,
+    ``pair_dtype``, ``count_bits``), and the result carries the pair list
+    (``PeelResult.pairs``) for ``build_hierarchy``.
 
     ``fd_driver`` (csr engine only): ``"device"`` (default) peels each FD
     partition in a single ``lax.while_loop`` dispatch — zero host↔device
@@ -837,51 +854,78 @@ def _tip_spec_csr(
     fused: bool = False, sup0: Optional[np.ndarray] = None,
     wed: Optional[csr.Wedges] = None,
 ) -> PeelSpec:
-    """csr-engine tip spec: CD + FD on the flat wedge list — no dense
+    """csr-engine tip spec: CD + FD on the U pair list — no dense
     matrices anywhere.
 
-    Support init and every update are exact int32 ``segment_sum``s over
-    U-endpoint pairs; pair butterfly counts are static because the V side
-    is never peeled, so the engine is purely incremental (zero
-    re-counts).  ``use_pallas`` routes the CD delta through the blocked
+    The pair list (U pairs with a common neighbour, and how many) comes
+    from ``csr.tip_pairs``: one int8 adjacency product on the MXU where
+    ``csr.pair_source`` finds it fitting and cheaper, else the host
+    wedge list; ``wed`` injects a prebuilt wedge or pair list.  Its
+    build is the ``tip.pairs`` span (args ``source``, ``rows``, ``k``,
+    ``pairs``, ``bits``).  Support init and every update are exact
+    integer ``segment_sum``s over the pairs; pair butterfly counts are
+    static because the V side is never peeled, so the engine is purely
+    incremental (zero re-counts).  Counts are int32, or int64 under
+    ``csr.count_scope`` where ``csr.count_bits`` finds ⋈init above
+    int32; the Pallas routes carry f32/int32 counts and refuse the
+    latter.  ``use_pallas`` routes the CD delta through the blocked
     row-sum kernel over the vertex-major slot layout
     (:func:`csr.tip_delta_slots`).  ``fused`` runs the FD phase through
     the fused ``kernels.fd_round`` launch (device driver: pack once,
     slice each partition from the shared stack; vmapped: the whole
     stack at once)."""
     n = gg.n_u
-    if wed is None:
-        wed = csr.build_wedges(gg)
-    pa = jnp.asarray(wed.pair_a)
-    pb = jnp.asarray(wed.pair_b)
-    pair_bf0 = wed.pair_butterflies0()
-    pbf = jnp.asarray(pair_bf0.astype(np.int32))
+    with obs.span("tip.pairs", cat="init") as sp:
+        if wed is None:
+            pairs = csr.tip_pairs(gg)
+        else:
+            pairs = wed if isinstance(wed, csr.TipPairs) else \
+                csr.TipPairs.of(wed)
+        pair_bf0 = pairs.pair_butterflies0()
+        sup_np = (csr.vertex_butterflies_csr(pairs) if sup0 is None
+                  else np.asarray(sup0, dtype=np.int64))
+        bits = csr.count_bits(sup_np, pair_bf0)
+        if sp is not None:
+            sp.update(source=pairs.source, rows=pairs.rows, k=pairs.k,
+                      pairs=pairs.n_pairs, bits=bits)
+    stats.pair_source, stats.pair_dtype = pairs.source, pairs.dtype
+    stats.pair_rows, stats.pair_k = pairs.rows, pairs.k
+    stats.count_bits = bits
+    if bits == 64 and (use_pallas or fused):
+        raise OverflowError(
+            "tip supports exceed int32 and the Pallas tip kernels carry "
+            "f32/int32 counts; run without use_pallas/fused, whose "
+            "segment_sum path widens them to int64")
+    cdt = np.int64 if bits == 64 else np.int32
+    scope = partial(csr.count_scope, bits)
+    with scope():
+        pa = jnp.asarray(pairs.pair_a)
+        pb = jnp.asarray(pairs.pair_b)
+        pbf = jnp.asarray(pair_bf0.astype(cdt))
+        state = dict(support=jnp.asarray(sup_np.astype(cdt)))
     wu, _ = csr.wedge_workload(gg)
     wedge_w = wu.astype(np.float64)
 
-    sup_np = (csr.vertex_butterflies_csr(wed) if sup0 is None
-              else np.asarray(sup0, dtype=np.int64))
-    if sup_np.size and int(sup_np.max()) > 2 ** 31 - 1:
-        raise OverflowError("tip supports exceed int32; shard the graph")
-    state = dict(support=jnp.asarray(sup_np.astype(np.int32)))
-
     if use_pallas:
-        slots = csr.pack_tip_slots(wed, pair_bf0, sup=sup_np)
+        slots = csr.pack_tip_slots(pairs, pair_bf0, sup=sup_np)
         slot_partner = jnp.asarray(slots["partner"])
         slot_bf = jnp.asarray(slots["bf"])
 
     def cd_step(active: np.ndarray) -> np.ndarray:
-        if use_pallas:
-            delta = csr.tip_delta_slots(
-                jnp.asarray(active), slot_partner, slot_bf, n)
-        else:
-            delta = csr.tip_delta_csr(jnp.asarray(active), pa, pb, pbf, n)
-        state["support"] = state["support"] - delta
-        if wed.n_pairs:
+        with scope():
+            if use_pallas:
+                delta = csr.tip_delta_slots(
+                    jnp.asarray(active), slot_partner, slot_bf, n)
+            else:
+                delta = csr.tip_delta_csr(
+                    jnp.asarray(active), pa, pb, pbf, n)
+            state["support"] = state["support"] - delta
+            out = np.asarray(state["support"]).astype(np.int64)
+        if pairs.n_pairs:
             stats.updates += int(
-                np.count_nonzero(active[wed.pair_a] | active[wed.pair_b])
+                np.count_nonzero(active[pairs.pair_a] | active[pairs.pair_b])
             )
-        return np.asarray(state["support"]).astype(np.int64)
+        return out
 
     # fused device driver: pack the partition stack ONCE (lazily, on the
     # first fd_partition call — part/sup_init are fixed for the whole FD
@@ -899,7 +943,7 @@ def _tip_spec_csr(
                     from .distributed import pack_fd_partitions_tip_csr
 
                     fused_pack["p"] = pack_fd_partitions_tip_csr(
-                        wed, pair_bf0, part, sup_init,
+                        pairs, pair_bf0, part, sup_init,
                         int(part.max()) + 1 if part.size else 0,
                         bucket=True, stacked=True,
                     )
@@ -923,13 +967,16 @@ def _tip_spec_csr(
             theta[p["gids"][i][mm]] = (
                 np.asarray(theta_st[0]).astype(np.int64)[mm])
             return int(rounds[0]), 0, 0
-        rounds = _tip_fd_csr(
-            wed, pair_bf0, part, i, sup_init, theta, fd_driver=fd_driver)
+        with scope():
+            rounds = _tip_fd_csr(pairs, pair_bf0, part, i, sup_init, theta,
+                                 fd_driver=fd_driver, dtype=cdt)
         return rounds, 0, 0
 
     def fd_vmapped(part, sup_init, theta, n_parts):
-        rounds = _tip_fd_vmapped_csr(
-            wed, pair_bf0, part, sup_init, theta, n_parts, fused=fused)
+        with scope():
+            rounds = _tip_fd_vmapped_csr(
+                pairs, pair_bf0, part, sup_init, theta, n_parts,
+                fused=fused, dtype=cdt)
         return rounds, 0
 
     return PeelSpec(
@@ -939,17 +986,19 @@ def _tip_spec_csr(
         cd_step=cd_step,
         fd_partition=fd_partition,
         fd_vmapped=fd_vmapped,
+        pairs=pairs,
     )
 
 
 def _tip_fd_csr(
-    wed: csr.Wedges,
+    wed: csr.TipPairs,
     pair_bf0: np.ndarray,
     part: np.ndarray,
     i: int,
     sup_init: np.ndarray,
     theta: np.ndarray,
     fd_driver: str = "device",
+    dtype=np.int32,
 ) -> int:
     """Bottom-up peel of partition i on the pair list.
 
@@ -960,7 +1009,8 @@ def _tip_fd_csr(
     ``fd_driver="device"`` (default) runs the whole cascade in one
     ``lax.while_loop`` (:func:`_fd_tip_device`) — a single dispatch per
     partition, zero host round-trips.  ``"host"`` keeps the per-round
-    dispatch loop (the PR-1 baseline, benchmarked against).
+    dispatch loop (the PR-1 baseline, benchmarked against).  ``dtype``
+    is the device count width (int64 inside ``csr.count_scope(64)``).
     """
     with obs.span("fd.pack", cat="fd.pack"):
         mine = part == i
@@ -977,17 +1027,17 @@ def _tip_fd_csr(
             # per size bucket, not once per partition
             size = _bucket_pad(int(mask.sum()))
             args = (
-                jnp.asarray(mine), jnp.asarray(support0.astype(np.int32)),
+                jnp.asarray(mine), jnp.asarray(support0.astype(dtype)),
                 jnp.asarray(_pad_zeros(wed.pair_a[mask], size)),
                 jnp.asarray(_pad_zeros(wed.pair_b[mask], size)),
-                jnp.asarray(_pad_zeros(pair_bf0[mask].astype(np.int32),
+                jnp.asarray(_pad_zeros(pair_bf0[mask].astype(dtype),
                                        size)),
                 n,
             )
         else:
             pa = jnp.asarray(wed.pair_a[mask])
             pb = jnp.asarray(wed.pair_b[mask])
-            pbf = jnp.asarray(pair_bf0[mask].astype(np.int32))
+            pbf = jnp.asarray(pair_bf0[mask].astype(dtype))
 
     cap = obs.fd_ring_cap()
     if fd_driver == "device":
@@ -1020,13 +1070,14 @@ def _tip_fd_csr(
 
 
 def _tip_fd_vmapped_csr(
-    wed: csr.Wedges,
+    wed: csr.TipPairs,
     pair_bf0: np.ndarray,
     part: np.ndarray,
     sup_init: np.ndarray,
     theta: np.ndarray,
     n_parts: int,
     fused: bool = False,
+    dtype=np.int32,
 ) -> np.ndarray:
     """Single-dispatch tip Phase 2: pack all partitions into one stacked
     shape-bucketed layout and peel them in ONE batched while_loop
@@ -1043,7 +1094,8 @@ def _tip_fd_vmapped_csr(
     from .distributed import pack_fd_partitions_tip_csr
 
     packed = pack_fd_partitions_tip_csr(
-        wed, pair_bf0, part, sup_init, n_parts, bucket=True, stacked=fused
+        wed, pair_bf0, part, sup_init, n_parts, bucket=True, stacked=fused,
+        dtype=dtype,
     )
     cap = obs.fd_ring_cap()
     if fused:
@@ -1394,7 +1446,9 @@ def _wing_spec_csr(
     sup0 = (csr.edge_butterflies0(wed) if sup0 is None
             else np.asarray(sup0, dtype=np.int64))
     if sup0.size and int(sup0.max()) > 2 ** 31 - 1:
-        raise OverflowError("wing supports exceed int32; shard the graph")
+        raise OverflowError("wing supports exceed int32, the width of the "
+                            "wing path's device counts")
+    stats.count_bits = 32
     state = dict(
         alive_w=jnp.ones((wed.n_wedges,), dtype=bool),
         Wp=csr.pair_wedge_counts(wed),

@@ -66,6 +66,13 @@ class PeelStats:
     engine: str = ""         # engine that produced THESE round counts
     fd_driver: str = ""      # "device" (one while_loop/partition) | "host"
     side: str = ""           # tip: peeled vertex set "u"|"v"; wing: ""
+    # tip csr: how the pair list was made ("mxu" | "wedges"), the
+    # product's shape as it ran (padding included) and operand dtype
+    pair_source: str = ""
+    pair_rows: int = 0
+    pair_k: int = 0
+    pair_dtype: str = ""
+    count_bits: int = 0      # csr: device width of supports and θ (32|64)
 
     @property
     def rho(self) -> int:
@@ -121,6 +128,9 @@ class PeelResult:
     # per-round work curves, present only when the obs layer was
     # enabled during the run (obs.enable(); see docs/OBSERVABILITY.md)
     timeline: Optional["obs.PeelTimeline"] = None
+    # tip csr: the pair list CD and FD peeled on (csr.TipPairs), which
+    # the hierarchy build reuses instead of recomputing it
+    pairs: Optional[object] = None
 
     def provenance(self) -> dict:
         """Everything besides θ a downstream consumer (the hierarchy
@@ -184,6 +194,7 @@ class PeelSpec:
     cd_step: Callable         # active mask -> refreshed int64 supports
     fd_partition: Optional[Callable] = None
     fd_vmapped: Optional[Callable] = None
+    pairs: Optional[object] = None    # tip csr: csr.TipPairs
 
 
 # =====================================================================
@@ -446,6 +457,7 @@ def decompose(
         support_init=sup_init,
         stats=stats,
         timeline=timeline,
+        pairs=spec.pairs,
     )
 
 
@@ -488,10 +500,12 @@ def _fd_cascade(mine: np.ndarray, support0: np.ndarray, theta: np.ndarray,
     return rounds
 
 
-# sentinel for masked-out supports in the k-advance; must be >= any real
-# support (engines guard supports <= int32 max), else the while_loop can
-# never peel the last entities and spins forever
-_FD_BIG = jnp.iinfo(jnp.int32).max
+def _fd_big(sup: jax.Array) -> int:
+    """The sentinel for masked-out supports in the k-advance: the largest
+    value of ``sup``'s dtype (int32, or int64 where ``csr.count_bits``
+    asks for it).  It must be ≥ any real support, else the while_loop can
+    never peel the last entities and spins forever."""
+    return jnp.iinfo(sup.dtype).max
 
 
 def _bucket_pad(n: int, floor: int = 128) -> int:
@@ -550,7 +564,7 @@ def _fd_round(state, update):
     wing driver that relaunches at shrinking wedge sizes
     (``peel._fd_wing_chunk``)."""
     alive, sup, aux, theta, k, rounds, nupd = state
-    cur = jnp.where(alive, sup, _FD_BIG)
+    cur = jnp.where(alive, sup, _fd_big(sup))
     k = jnp.maximum(k, jnp.min(cur))
     S = alive & (sup <= k)
     # S is non-empty whenever alive is (k ≥ min alive support), so every
@@ -588,7 +602,7 @@ def _fd_while_vmapped(mine: jax.Array, sup0: jax.Array, update, aux):
     def body(state):
         alive, sup, aux, theta, k, rounds, nupd = state
         live = jnp.any(alive, axis=1)
-        cur = jnp.where(alive, sup, _FD_BIG)
+        cur = jnp.where(alive, sup, _fd_big(sup))
         k = jnp.maximum(k, jnp.min(cur, axis=1))
         S = alive & (sup <= k[:, None])
         # per live partition S is non-empty (k ≥ its min alive support):
@@ -635,9 +649,9 @@ def _fd_while_fused(state0, round_fn):
 # Telemetry-ON twins of the FD cascade drivers (obs counter rings)
 # =====================================================================
 # Each ``*_rings`` function repeats its twin's loop algebra VERBATIM and
-# additionally threads preallocated per-round int32 counter rings
-# through the carry — dying count, frontier size, k-advance, update
-# count — written at slot ``min(round, cap-1)`` (first cap-1 rounds
+# additionally threads preallocated per-round counter rings through
+# the carry — dying count, frontier size, k-advance (in the supports'
+# dtype), update count (int32) — written at slot ``min(round, cap-1)`` (first cap-1 rounds
 # plus the final round survive an overflow; the drain flags it
 # ``truncated``).  They are separate functions, not a branch inside the
 # twins, so the telemetry-OFF path traces the byte-identical jaxpr — a
@@ -659,7 +673,7 @@ def _fd_while_device_rings(mine: jax.Array, sup0: jax.Array, update, aux,
     def body(state):
         alive, sup, aux, theta, k, rounds, nupd, rings = state
         died_r, fr_r, k_r, nu_r = rings
-        cur = jnp.where(alive, sup, _FD_BIG)
+        cur = jnp.where(alive, sup, _fd_big(sup))
         k = jnp.maximum(k, jnp.min(cur))
         S = alive & (sup <= k)
         theta = jnp.where(S, k, theta)
@@ -667,9 +681,11 @@ def _fd_while_device_rings(mine: jax.Array, sup0: jax.Array, update, aux,
         loss, aux, nu = update(S, aux)
         slot = jnp.minimum(rounds, cap - 1)
         rings = (
-            died_r.at[slot].set(jnp.sum(S.astype(jnp.int32))),
-            fr_r.at[slot].set(jnp.sum(alive.astype(jnp.int32))),
-            k_r.at[slot].set(k.astype(jnp.int32)),
+            died_r.at[slot].set(
+                jnp.sum(S.astype(jnp.int32)).astype(jnp.int32)),
+            fr_r.at[slot].set(
+                jnp.sum(alive.astype(jnp.int32)).astype(jnp.int32)),
+            k_r.at[slot].set(k),
             nu_r.at[slot].set(jnp.asarray(nu).astype(jnp.int32)),
         )
         return (alive, sup - loss, aux, theta, k, rounds + 1, nupd + nu,
@@ -679,7 +695,7 @@ def _fd_while_device_rings(mine: jax.Array, sup0: jax.Array, update, aux,
     zero_s = jnp.min(zero_e)
     zring = jnp.zeros((cap,), jnp.int32)
     init = (mine, sup0, aux, zero_e, zero_s, zero_s, zero_s,
-            (zring, zring, zring, zring))
+            (zring, zring, jnp.zeros((cap,), sup0.dtype), zring))
     out = jax.lax.while_loop(cond, body, init)
     return out[3], out[5], out[6], out[7]
 
@@ -700,7 +716,7 @@ def _fd_while_vmapped_rings(mine: jax.Array, sup0: jax.Array, update, aux,
         alive, sup, aux, theta, k, rounds, nupd, it, rings = state
         died_r, fr_r, k_r, nu_r = rings
         live = jnp.any(alive, axis=1)
-        cur = jnp.where(alive, sup, _FD_BIG)
+        cur = jnp.where(alive, sup, _fd_big(sup))
         k = jnp.maximum(k, jnp.min(cur, axis=1))
         S = alive & (sup <= k[:, None])
         theta = jnp.where(S, k[:, None], theta)
@@ -708,9 +724,11 @@ def _fd_while_vmapped_rings(mine: jax.Array, sup0: jax.Array, update, aux,
         loss, aux, nu = update(S, aux)
         slot = jnp.minimum(it, cap - 1)
         rings = (
-            died_r.at[slot].set(jnp.sum(S.astype(jnp.int32), axis=1)),
-            fr_r.at[slot].set(jnp.sum(alive.astype(jnp.int32), axis=1)),
-            k_r.at[slot].set(k.astype(jnp.int32)),
+            died_r.at[slot].set(
+                jnp.sum(S.astype(jnp.int32), axis=1).astype(jnp.int32)),
+            fr_r.at[slot].set(
+                jnp.sum(alive.astype(jnp.int32), axis=1).astype(jnp.int32)),
+            k_r.at[slot].set(k),
             nu_r.at[slot].set(jnp.asarray(nu).astype(jnp.int32)),
         )
         return (alive, sup - loss, aux, theta, k,
@@ -721,7 +739,8 @@ def _fd_while_vmapped_rings(mine: jax.Array, sup0: jax.Array, update, aux,
     B = sup0.shape[0]
     zrow = jnp.zeros((cap, B), jnp.int32)
     init = (mine, sup0, aux, zero_e, zero_p, zero_p, jnp.int32(0),
-            jnp.int32(0), (zrow, zrow, zrow, jnp.zeros((cap,), jnp.int32)))
+            jnp.int32(0), (zrow, zrow, jnp.zeros((cap, B), sup0.dtype),
+                           jnp.zeros((cap,), jnp.int32)))
     out = jax.lax.while_loop(cond, body, init)
     return out[3], out[5], out[6], out[8]
 

@@ -192,8 +192,13 @@ def _component_labels_per_level(
     levels: np.ndarray,
     kind: str,
     level_block: int = 32,
+    pairs: Optional[csr.TipPairs] = None,
 ) -> np.ndarray:
     """(L, n_entities) int64 component labels, _BIG-marked where dead.
+
+    Tip connectivity reads the U pairs sharing ≥ 2 neighbours: those of
+    ``pairs`` (the decomposition's own list) when given, else of
+    ``csr.tip_pairs`` — never the wedge list.
 
     Levels are processed in fixed chunks of ``level_block`` (all-dead
     padded to one compiled shape): the propagation state is
@@ -207,8 +212,8 @@ def _component_labels_per_level(
         return np.zeros((0, n_ent), dtype=np.int64)
 
     with obs.span("hierarchy.wedges", cat="hierarchy"):
-        wed = csr.build_wedges(gg)
         if kind == "wing":
+            wed = csr.build_wedges(gg)
             we1 = jnp.asarray(wed.wedge_e1)
             we2 = jnp.asarray(wed.wedge_e2)
             wp = jnp.asarray(wed.wedge_pair)
@@ -216,11 +221,13 @@ def _component_labels_per_level(
             inc_g = jnp.concatenate([wp, wp])
             n_groups = wed.n_pairs
         else:
-            # pairs with ≥ 2 wedges share a butterfly (V is never peeled,
-            # so W0 is the pair's wedge count at every level)
-            conn_p = wed.W0 >= 2
-            pa = wed.pair_a[conn_p].astype(np.int32)
-            pb = wed.pair_b[conn_p].astype(np.int32)
+            # pairs with ≥ 2 common neighbours share a butterfly (V is
+            # never peeled, so W0 is the pair's count at every level)
+            if pairs is None or pairs.n_u != n_ent:
+                pairs = csr.tip_pairs(gg)
+            conn_p = pairs.W0 >= 2
+            pa = pairs.pair_a[conn_p].astype(np.int32)
+            pb = pairs.pair_b[conn_p].astype(np.int32)
             pid = np.arange(pa.size, dtype=np.int32)
             inc_e = jnp.asarray(np.concatenate([pa, pb]))
             inc_g = jnp.asarray(np.concatenate([pid, pid]))
@@ -270,6 +277,52 @@ def _dfs_order(n_nodes: int, child_off, child_ids):
     return tin, tout
 
 
+def _lca(a: np.ndarray, b: np.ndarray, parent, tin, tout) -> np.ndarray:
+    """Lowest common ancestor of each node pair (a[i], b[i]), by binary
+    lifting over the preorder stamps (x is an ancestor of y iff
+    tin[x] ≤ tin[y] < tout[x])."""
+    up = np.where(parent < 0, 0, parent).astype(np.int64)
+    jumps = [up]
+    while True:                     # 2^j-th ancestors until all hit the root
+        nxt = jumps[-1][jumps[-1]]
+        if np.array_equal(nxt, jumps[-1]):
+            break
+        jumps.append(nxt)
+
+    def above(x, y):
+        return (tin[x] <= tin[y]) & (tin[y] < tout[x])
+
+    x = a.copy()
+    for j in reversed(jumps):       # climb to just below the common part
+        y = j[x]
+        x = np.where(above(y, b), x, y)
+    return np.where(above(a, b), a, up[x])
+
+
+def _distinct_per_subtree(item_node: np.ndarray, colour: np.ndarray,
+                          subtree) -> np.ndarray:
+    """Distinct colours among the items of every node's subtree, for
+    items placed at ``item_node``: per colour, +1 at each distinct node
+    holding it and −1 at the LCA of each node and the next in preorder,
+    then subtree sums over the preorder, O(items · log depth).
+    ``subtree`` is (parent, tin, tout)."""
+    parent, tin, tout = subtree
+    n_nodes = parent.size
+    out = np.zeros(n_nodes, dtype=np.int64)
+    if item_node.size == 0:
+        return out
+    at_tin = np.argsort(tin)
+    key = np.unique(colour.astype(np.int64) * n_nodes + tin[item_node])
+    col, node = key // n_nodes, at_tin[key % n_nodes]
+    val = np.bincount(node, minlength=n_nodes).astype(np.int64)
+    same = col[1:] == col[:-1]
+    lca = _lca(node[:-1][same], node[1:][same], parent, tin, tout)
+    val -= np.bincount(lca, minlength=n_nodes)
+    csum = np.zeros(n_nodes + 1, dtype=np.int64)
+    np.cumsum(val[at_tin], out=csum[1:])
+    return csum[tout] - csum[tin]
+
+
 def build_hierarchy(
     g: BipartiteGraph,
     result: Union[PeelResult, np.ndarray],
@@ -283,8 +336,11 @@ def build_hierarchy(
     Traced under a ``hierarchy.build`` span when the obs layer is
     enabled: ``hierarchy.labels`` (the device label program, with the
     wedge rebuild and its uploads as ``hierarchy.wedges``) and
-    ``hierarchy.assemble`` (the host assembly, with the per-node loop as
-    ``hierarchy.node_stats``).
+    ``hierarchy.assemble`` (the host assembly, with the induced-subgraph
+    stats, vectorized over the edges, as ``hierarchy.node_stats``).
+    A tip build reuses the decomposition's pair list
+    (``PeelResult.pairs``) for its connectivity; θ levels are compared
+    in int64 on the host, so 64-bit tip numbers need nothing else.
 
     ``result`` is a :class:`~repro.core.peel.PeelResult` from ANY engine
     (``dense`` / ``beindex`` / ``csr`` — their θ are bit-identical, so
@@ -306,9 +362,12 @@ def _build_hierarchy_impl(g, result, kind, side, meta, level_block):
     if kind not in ("wing", "tip"):
         raise ValueError(kind)
     gg = g if (kind == "wing" or side == "u") else g.transpose()
+    pairs = None
     if isinstance(result, PeelResult):
         theta = np.asarray(result.theta, dtype=np.int64)
         prov = result.provenance()
+        if kind == "tip":
+            pairs = result.pairs
     else:
         theta = np.asarray(result, dtype=np.int64)
         prov = {}
@@ -324,7 +383,7 @@ def _build_hierarchy_impl(g, result, kind, side, meta, level_block):
         if sp is not None:
             sp.update(levels=int(levels.size))
         labels = _component_labels_per_level(
-            gg, theta, levels, kind, level_block=level_block
+            gg, theta, levels, kind, level_block=level_block, pairs=pairs
         )
     with obs.span("hierarchy.assemble", cat="hierarchy"):
         return _assemble_from_labels(
@@ -397,33 +456,26 @@ def _assemble_from_labels(
     estart = np.searchsorted(sorted_tin, tin).astype(np.int64)
     eend = np.searchsorted(sorted_tin, tout).astype(np.int64)
 
-    # ---- induced-subgraph stats per node
-    node_m = np.zeros(n_nodes, dtype=np.int64)
-    node_nu = np.zeros(n_nodes, dtype=np.int64)
-    node_nv = np.zeros(n_nodes, dtype=np.int64)
+    # ---- induced-subgraph stats per node, vectorized over the edges
     with obs.span("hierarchy.node_stats", cat="hierarchy") as sp:
         if sp is not None:
             sp.update(n_nodes=int(n_nodes))
+        size = eend - estart
+        subtree = (parent, tin, tout)
         if kind == "wing":
-            eu = gg.edges[:, 0]
-            ev = gg.edges[:, 1]
-            for x in range(n_nodes):
-                ids = ent_order[estart[x]:eend[x]]
-                node_m[x] = ids.size
-                node_nu[x] = np.unique(eu[ids]).size
-                node_nv[x] = np.unique(ev[ids]).size
+            node_m = size
+            node_nu = _distinct_per_subtree(
+                entity_node, gg.edges[:, 0], subtree)
+            node_nv = _distinct_per_subtree(
+                entity_node, gg.edges[:, 1], subtree)
         else:
             du, _ = gg.degrees()
-            offu, nbru, _ = gg.csr_u()  # per-U CSR: neighbors are V ids
-            for x in range(n_nodes):
-                us = ent_order[estart[x]:eend[x]]
-                node_nu[x] = us.size
-                node_m[x] = int(du[us].sum())
-                if us.size:
-                    vs = np.concatenate(
-                        [nbru[offu[u]:offu[u + 1]] for u in us]
-                    )
-                    node_nv[x] = np.unique(vs).size
+            csum = np.zeros(n_ent + 1, dtype=np.int64)
+            np.cumsum(du[ent_order], out=csum[1:])
+            node_m = csum[eend] - csum[estart]
+            node_nu = size
+            node_nv = _distinct_per_subtree(
+                entity_node[gg.edges[:, 0]], gg.edges[:, 1], subtree)
 
     span = node_nu * node_nv
     density = np.divide(

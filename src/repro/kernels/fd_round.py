@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 
 __all__ = ["MOSAIC_LIMITS", "fd_round_wing_pallas", "fd_round_tip_pallas"]
 
-_BIG = jnp.iinfo(jnp.int32).max  # == peelspec._FD_BIG
+_BIG = jnp.iinfo(jnp.int32).max  # == peelspec._fd_big of int32 supports
 
 # Why Mosaic refuses this kernel (compiled for a v5e): the (1, E) /
 # (1, 1) / (1, R) blocks over (B, ...) state break the (8, 128) tiling

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import csr
 from repro.core.peel import (
     _FD_COMPACT_FLOOR, _fd_tip_device, _fd_wing_chunk, _fd_wing_device)
 from repro.kernels import ops as kops
@@ -136,3 +137,42 @@ def test_tip_fd_while_compiles_for_v5e(one_chip):
         _s(s, (WEDGE_BUCKET,), jnp.int32), _s(s, (WEDGE_BUCKET,), jnp.int32),
         _s(s, (WEDGE_BUCKET,), jnp.int32), n=N_U))
     assert "while" in hlo
+
+
+# graph500_hub.tip_v at scale_u 18: the 512-vertex side over 2^18 items,
+# 2,079,918 edges (bucket 2,097,152), 130,601 pairs (bucket 131,072)
+HUB_ROWS, HUB_K, HUB_EDGES, HUB_PAIRS = 512, 1 << 18, 1 << 21, 1 << 17
+
+
+def test_tip_pair_counts_compiles_for_v5e(one_chip):
+    """The pair-count programs of the hub cell: the edges set in an int8
+    adjacency, then its product with itself on the MXU, int32 out, as
+    one dot and nothing else."""
+    s = one_chip
+    adj = _compile(csr.tip_pair_adjacency.lower(
+        _s(s, (HUB_EDGES,), jnp.int32), rows=HUB_ROWS, k=HUB_K))
+    # every operation of the adjacency is named after it, no index sort
+    entry = adj[adj.index("ENTRY"):]
+    assert " sort(" not in entry
+    for line in entry.splitlines():
+        if "fusion(" in line:
+            assert 'op_name="jit(tip_pair_adjacency)/' in line, line
+    hlo = _compile(csr.tip_pair_counts.lower(
+        _s(s, (HUB_ROWS, HUB_K), jnp.int8)))
+    ops = [line for line in hlo[hlo.index("ENTRY"):].splitlines()
+           if " = " in line and "parameter(" not in line]
+    # a device trace times the product alone by this one name
+    assert len(ops) == 1 and "s32[512,512]" in ops[0], ops
+    assert 'op_name="jit(tip_pair_counts)/dot_general"' in ops[0]
+
+
+def test_wide_tip_fd_while_compiles_for_v5e(one_chip):
+    """The hub cell's device FD program with 64-bit supports and pair
+    counts, traced in the scoped 64-bit mode the engine uses."""
+    s = one_chip
+    with csr.count_scope(64):
+        hlo = _compile(_fd_tip_device.lower(
+            _s(s, (HUB_ROWS,), bool), _s(s, (HUB_ROWS,), jnp.int64),
+            _s(s, (HUB_PAIRS,), jnp.int32), _s(s, (HUB_PAIRS,), jnp.int32),
+            _s(s, (HUB_PAIRS,), jnp.int64), n=HUB_ROWS))
+    assert "while" in hlo and "s64[512]" in hlo
