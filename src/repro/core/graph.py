@@ -11,6 +11,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from .. import obs
+
 __all__ = [
     "BipartiteGraph",
     "random_bipartite",
@@ -55,22 +57,30 @@ class BipartiteGraph:
 
     # ----------------------------------------------------------------- CSR
     def csr_u(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Per-U CSR: (offsets[n_u+1], neighbor v ids, edge ids)."""
-        order = np.lexsort((self.edges[:, 1], self.edges[:, 0]))
-        e = self.edges[order]
+        """Per-U CSR: (offsets[n_u+1], neighbor v ids, edge ids).
+
+        The edge order is a stable argsort of the packed key
+        ``u·n_v + v``: the lexicographic (u, v) order, ties (duplicate
+        rows of a graph built directly) kept in row order."""
+        order = np.argsort(_pack(self.edges[:, 0], self.edges[:, 1], self.n_v),
+                           kind="stable")
         du, _ = self.degrees()
         off = np.zeros(self.n_u + 1, dtype=np.int64)
         np.cumsum(du, out=off[1:])
-        return off, e[:, 1].astype(np.int32), order.astype(np.int32)
+        return off, self.edges[order, 1].astype(np.int32), order.astype(np.int32)
 
     def csr_v(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Per-V CSR: (offsets[n_v+1], neighbor u ids, edge ids)."""
-        order = np.lexsort((self.edges[:, 0], self.edges[:, 1]))
-        e = self.edges[order]
+        """Per-V CSR: (offsets[n_v+1], neighbor u ids, edge ids).
+
+        The edge order is a stable argsort of the packed key
+        ``v·n_u + u``: the lexicographic (v, u) order, ties kept in row
+        order."""
+        order = np.argsort(_pack(self.edges[:, 1], self.edges[:, 0], self.n_u),
+                           kind="stable")
         _, dv = self.degrees()
         off = np.zeros(self.n_v + 1, dtype=np.int64)
         np.cumsum(dv, out=off[1:])
-        return off, e[:, 0].astype(np.int32), order.astype(np.int32)
+        return off, self.edges[order, 0].astype(np.int32), order.astype(np.int32)
 
     # --------------------------------------------------------------- dense
     def adjacency(self, dtype=np.float32) -> np.ndarray:
@@ -81,21 +91,48 @@ class BipartiteGraph:
 
     def transpose(self) -> "BipartiteGraph":
         """Swap U and V (tip decomposition of the V side peels the
-        transpose's U side)."""
-        e = self.edges[:, ::-1].copy()
-        order = np.lexsort((e[:, 1], e[:, 0]))
-        return BipartiteGraph(self.n_v, self.n_u, e[order])
+        transpose's U side).
+
+        The rows are the sorted packed keys ``v·n_u + u``, decoded: the
+        lexicographic (v, u) order.  Nothing is deduplicated, so a graph
+        built directly with duplicate rows keeps them."""
+        key = np.sort(_pack(self.edges[:, 1], self.edges[:, 0], self.n_u))
+        return BipartiteGraph(self.n_v, self.n_u,
+                              _unpack(key, self.n_u, self.edges.dtype))
 
     # --------------------------------------------------------------- build
     @staticmethod
     def from_edges(n_u: int, n_v: int, edges) -> "BipartiteGraph":
-        """Canonical constructor: dedup + lexsort + bounds-check edges."""
+        """Canonical constructor: bounds-check, dedup and sort edges.
+
+        The ids are checked on the raw rows first, so an id out of range
+        cannot alias into another row's packed key ``u·n_v + v``; the
+        unique keys, decoded, are the deduplicated rows in lexicographic
+        (u, v) order — ``np.unique(edges, axis=0)``, as a C-contiguous
+        (m, 2) int32 array."""
         e = np.asarray(edges, dtype=np.int32).reshape(-1, 2)
-        if e.size:
-            e = np.unique(e, axis=0)
-            assert e[:, 0].min() >= 0 and e[:, 0].max() < n_u, "u id out of range"
-            assert e[:, 1].min() >= 0 and e[:, 1].max() < n_v, "v id out of range"
+        with obs.span("graph.from_edges", cat="graph",
+                       m_in=e.shape[0]) as sp:
+            if e.size:
+                assert e[:, 0].min() >= 0 and e[:, 0].max() < n_u, "u id out of range"
+                assert e[:, 1].min() >= 0 and e[:, 1].max() < n_v, "v id out of range"
+                e = _unpack(np.unique(_pack(e[:, 0], e[:, 1], n_v)), n_v, np.int32)
+            if sp is not None:
+                sp.update(m_out=e.shape[0])
         return BipartiteGraph(int(n_u), int(n_v), e)
+
+
+def _pack(major: np.ndarray, minor: np.ndarray, n_minor: int) -> np.ndarray:
+    """The int64 key ``major·n_minor + minor``: its sorted order is the
+    lexicographic (major, minor) order.  Int32 ids keep it below 2**62."""
+    return major.astype(np.int64) * int(n_minor) + minor
+
+
+def _unpack(key: np.ndarray, n_minor: int, dtype) -> np.ndarray:
+    """The (m, 2) rows ``(major, minor)`` of packed keys, C-contiguous."""
+    out = np.empty((key.shape[0], 2), dtype=dtype)
+    np.divmod(key, int(n_minor), out=(out[:, 0], out[:, 1]), casting="unsafe")
+    return out
 
 
 # -------------------------------------------------------------- generators

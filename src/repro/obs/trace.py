@@ -24,6 +24,9 @@ Span taxonomy (see docs/OBSERVABILITY.md):
 ====================  ==========  ===========================================
 cat                   ph          meaning
 ====================  ==========  ===========================================
+``graph``             X           ``graph.from_edges``: one canonical graph
+                                  build (bounds check, dedup, sort); args
+                                  ``m_in``, ``m_out``
 ``peel``              X           one ``decompose()`` / distributed run
 ``cd``                X           Phase 1 (cover decomposition) total
 ``cd.select``         X           one partition's range selection; count ==
