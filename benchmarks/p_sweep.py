@@ -5,8 +5,6 @@ wide range of P; small P starves FD parallelism, large P adds CD rounds.
 """
 from __future__ import annotations
 
-import numpy as np
-
 from repro.core.graph import paper_proxy_dataset, powerlaw_bipartite
 from repro.core.peel import wing_decomposition
 
@@ -47,28 +45,14 @@ def run(small: bool = True):
         emit(f"psweep.{name}.P{P}.csr_vmapped", t_v,
              rho_fd_max=res_v.stats.rho_fd_max, fd_driver="vmapped",
              vs_device=round(t_v / max(t_d, 1e-9), 2))
-    # fused round P-sensitivity: per-round dispatch tail goes to zero,
-    # so the sweep isolates pure lock-step padding cost.  Measured on
-    # the pl60 proxy, NOT fr — the kernel interprets on CPU (orders
-    # slower; fr-scale wedge lists blow the smoke-time budget) and the
-    # dispatch story is the accelerator target.  Parity asserted per P;
-    # report.py renders fd.fused/unfused.
+    # the vmapped driver's P-sensitivity on the pl60 proxy
     gp = powerlaw_bipartite(60, 40, 260, seed=7)
     for P in ps:
         res_v, t_v = timed(wing_decomposition, gp, P=P, engine="csr",
                            fd_driver="vmapped", repeat=2)
-        res_f, t_f = timed(
-            wing_decomposition, gp, P=P, engine="csr",
-            fd_driver="vmapped", fused=True, repeat=2)
-        assert np.array_equal(res_f.theta, res_v.theta)
-        assert res_f.stats.rho_fd_max == res_v.stats.rho_fd_max
         emit(f"psweep.pl60.P{P}.csr_vmapped", t_v,
              rho_fd_max=res_v.stats.rho_fd_max, fd_driver="vmapped",
              parts=res_v.stats.p_effective)
-        emit(f"psweep.pl60.P{P}.csr_vmapped_fused", t_f,
-             fd_driver="vmapped", fd_round="fused",
-             vs_unfused=round(t_f / max(t_v, 1e-9), 2),
-             note="interpret-mode;compiled-on-TPU-target")
 
 
 if __name__ == "__main__":

@@ -41,10 +41,6 @@ AB_PAIRS = [
     ("csr_pal", "csr", "cd.pair_aligned/wedge", ("scaling.",)),
     ("csr_pal_hier", "csr_pal", "cd.hier/flat", ("scaling.",)),
     ("tip_aligned", "tip_csr", "cd.aligned/roundrobin", ("scaling.",)),
-    ("pbng_csr_vmapped_fused", "pbng_csr_vmapped", "fd.fused/unfused",
-     ("wing.pl", "tip.pl")),
-    ("csr_vmapped_fused", "csr_vmapped", "fd.fused/unfused",
-     ("psweep.",)),
 ]
 
 

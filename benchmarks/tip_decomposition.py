@@ -57,27 +57,15 @@ def run(small: bool = True):
                 emit(f"tip.{name}{side.upper()}.bup", t_bup,
                      kind="sequential-oracle")
 
-    # fused-round A/B on one synthetic graph (same rationale as the
-    # wing pl60 rows: the kernel interprets on CPU, so the row
-    # certifies bit-parity; the zero-dispatch win is the accelerator
-    # story).
+    # the vmapped driver on one synthetic graph
     from repro.core.graph import powerlaw_bipartite
 
     gp = powerlaw_bipartite(60, 40, 260, seed=7)
-    res_v, t_v = timed(
+    _, t_v = timed(
         tip_decomposition, gp, side="u", P=6, engine="csr",
         fd_driver="vmapped", repeat=2)
-    res_f, t_f = timed(
-        tip_decomposition, gp, side="u", P=6, engine="csr",
-        fd_driver="vmapped", fused=True, repeat=2)
-    assert np.array_equal(res_f.theta, res_v.theta)
-    assert res_f.stats.rho_fd_max == res_v.stats.rho_fd_max
     emit("tip.pl60U.pbng_csr_vmapped", t_v, engine="csr",
          fd_driver="vmapped", side="u")
-    emit("tip.pl60U.pbng_csr_vmapped_fused", t_f, engine="csr",
-         fd_driver="vmapped", side="u", fd_round="fused",
-         vs_unfused=round(t_f / max(t_v, 1e-9), 2),
-         note="interpret-mode;compiled-on-TPU-target")
 
 
 if __name__ == "__main__":

@@ -146,22 +146,6 @@ def run(small: bool = True):
          fd_driver="vmapped", fd_update="pallas",
          note="interpret-mode;compiled-on-TPU-target")
 
-    # fused-round A/B: the whole FD round body as ONE pallas_call
-    # (kernels/fd_round.py) vs the unfused driver.  Same caveat as the
-    # in-loop kernel row: on CPU the kernel interprets (slower), the
-    # row certifies bit-parity; the dispatch-latency story is the
-    # accelerator target.  report.py renders fd.fused/unfused.
-    res_f, t_f = timed(
-        wing_decomposition, gp, P=6, engine="csr", fd_driver="vmapped",
-        fused=True, repeat=2)
-    assert np.array_equal(res_f.theta, res_v.theta)
-    assert res_f.stats.updates == res_v.stats.updates
-    assert res_f.stats.rho_fd_max == res_v.stats.rho_fd_max
-    emit("wing.pl60.pbng_csr_vmapped_fused", t_f, engine="csr",
-         fd_driver="vmapped", fd_round="fused",
-         vs_unfused=round(t_f / max(t_v, 1e-9), 2),
-         note="interpret-mode;compiled-on-TPU-target")
-
 
 if __name__ == "__main__":
     run(small=False)

@@ -13,8 +13,7 @@ Phases, all in this one process (the chip belongs to one process):
     ``tests/goldens/real_graphs.json``.  Also asserts that no Pallas
     kernel runs in interpret mode, that every jitted Pallas entry point
     those runs called compiles, at the shapes it was called with, to a
-    Mosaic kernel (``tpu_custom_call``), and that ``--fused-fd`` is
-    refused.
+    Mosaic kernel (``tpu_custom_call``).
 (b) full size — ``--kind wing|tip --engine csr`` with the launcher's
     default flags on the seeded skewed ``peel`` graph of
     ``tests/goldens/record_chip_smoke.py``; prints |E|, the wedge count,
@@ -65,7 +64,7 @@ from repro.launch.cache import enable_compile_cache  # noqa: E402
 from record_chip_smoke import (KINDS, forest_digest,  # noqa: E402
                                launcher_args, theta_sha, v_centred_wedges)
 
-# csr FD paths the launcher can take on one chip (--fused-fd is refused)
+# csr FD paths the launcher can take on one chip
 FD_PATHS = [("device", False), ("device", True),
             ("vmapped", False), ("vmapped", True)]
 # the jitted entry points through which --use-pallas reaches a kernel
@@ -159,7 +158,6 @@ def phase_a() -> None:
     from repro.core import ref
     from repro.core.graph import paper_proxy_dataset
     from repro.kernels import ops as kops
-    from repro.launch.peel import LaunchError
 
     check(kops.default_interpret() is False,
           "Pallas kernels compile for the chip (interpret mode off)")
@@ -192,14 +190,6 @@ def phase_a() -> None:
             check("tpu_custom_call" in txt,
                   f"{what} at {shapes}: the program it ran holds a Mosaic "
                   f"kernel (tpu_custom_call)")
-    try:
-        _launch(["--kind", "wing", "--engine", "csr", "--dataset", "fr",
-                 "--fused-fd"])
-        refused = None
-    except LaunchError as e:
-        refused = str(e)
-    check(refused is not None and "Mosaic" in refused,
-          f"--fused-fd refused on the chip: {refused}")
 
 
 # ---------------------------------------------------------------- (b)

@@ -53,7 +53,6 @@ from .peelspec import (  # noqa: F401 — canonical home is peelspec; kept
     _bucket_pad,
     _fd_cascade,
     _fd_while_device,
-    _fd_while_fused,
     _fd_while_vmapped,
     _find_range,
     _lpt_order,
@@ -83,7 +82,6 @@ def build_peel_spec(
     be: Optional[BEIndex] = None,
     fd_driver: str = "device",
     use_pallas: bool = False,
-    fused: bool = False,
     sup0: Optional[np.ndarray] = None,
     wed: Optional["csr.Wedges"] = None,
 ) -> PeelSpec:
@@ -116,20 +114,16 @@ def build_peel_spec(
         raise ValueError(fd_driver)
     if kind == "tip" and use_pallas and engine != "csr":
         raise ValueError("use_pallas applies to engine='csr' only")
-    if fused and engine != "csr":
-        raise ValueError("fused applies to engine='csr' only")
-    if fused and fd_driver == "host":
-        raise ValueError("fused requires fd_driver='device' or 'vmapped'")
     if kind == "tip":
         gg = g if side == "u" else g.transpose()
         if engine == "csr":
             return _tip_spec_csr(gg, stats, use_pallas=use_pallas,
-                                 fused=fused, sup0=sup0, wed=wed)
+                                 sup0=sup0, wed=wed)
         return _tip_spec_dense(gg, batch_recount, stats)
     if engine == "beindex":
         return _wing_spec_beindex(g, be, stats)
     if engine == "csr":
-        return _wing_spec_csr(g, stats, use_pallas=use_pallas, fused=fused,
+        return _wing_spec_csr(g, stats, use_pallas=use_pallas,
                               sup0=sup0, wed=wed)
     return _wing_spec_dense(g, stats, sup0=sup0)
 
@@ -332,113 +326,6 @@ def _fd_wing_vmapped_pallas_rings(slot_e1, slot_e2, valid0, W0, mine, sup0,
 
 
 # =====================================================================
-# Fused FD bodies — the whole round is ONE Pallas launch
-# =====================================================================
-def _wing_fused_setup(slot_e1, slot_e2, valid0, W0, mine, sup0):
-    from repro.kernels import ops as kops  # local import: keep core light
-
-    # loop-constant inits derived from inputs (cf. _fd_while_vmapped)
-    z = sup0 * 0
-    z1 = z[:, :1]
-    state0 = (
-        sup0.astype(jnp.int32), mine.astype(jnp.int32), z, z1, z1, z1,
-        valid0.astype(jnp.int32), W0.astype(jnp.float32),
-    )
-
-    def round_fn(sup, alive, theta, k, rounds, nupd, aslot, W):
-        return kops.fd_round_wing(
-            sup, alive, theta, k, rounds, nupd, aslot, W,
-            slot_e1, slot_e2)
-
-    return state0, round_fn
-
-
-def _fd_wing_fused_impl(
-    slot_e1: jax.Array,     # (B, R, K) int32 — local edge ids, sentinel E
-    slot_e2: jax.Array,
-    valid0: jax.Array,      # (B, R, K) bool — initial alive slots
-    W0: jax.Array,          # (B, R) int32 — alive wedges per slot row
-    mine: jax.Array,        # (B, E) bool
-    sup0: jax.Array,        # (B, E) int32
-):
-    """Zero-per-round-dispatch wing FD: the while_loop body is ONE fused
-    ``kernels.fd_round`` launch — k-advance, frontier compaction AND the
-    widow/survivor support update all in-kernel, no segment-sum/argmin
-    tail (cf. :func:`_fd_wing_vmapped_pallas`, which still scatters the
-    losses outside the kernel).  Returns (theta (B, E), rounds (B),
-    update count) bit-identical to the unfused drivers."""
-    state0, round_fn = _wing_fused_setup(
-        slot_e1, slot_e2, valid0, W0, mine, sup0)
-    out = peelspec._fd_while_fused(state0, round_fn)
-    return out[2], out[4][:, 0], jnp.sum(out[5])
-
-
-_fd_wing_fused = jax.jit(_fd_wing_fused_impl)
-
-
-def _fd_wing_fused_rings_impl(slot_e1, slot_e2, valid0, W0, mine, sup0,
-                              ring_cap: int):
-    """:func:`_fd_wing_fused_impl` + per-round counter rings derived
-    around the fused round (the kernel itself is untouched); the update
-    ring carries the state's *cumulative* per-partition counts — drain
-    with ``cumulative_updates=True``."""
-    state0, round_fn = _wing_fused_setup(
-        slot_e1, slot_e2, valid0, W0, mine, sup0)
-    out, rings = peelspec._fd_while_fused_rings(state0, round_fn, ring_cap)
-    return out[2], out[4][:, 0], jnp.sum(out[5]), rings
-
-
-_fd_wing_fused_rings = partial(
-    jax.jit, static_argnames=("ring_cap",))(_fd_wing_fused_rings_impl)
-
-
-def _tip_fused_setup(st_pa, st_pb, st_bf, mine, sup0):
-    from repro.kernels import ops as kops
-
-    z = sup0 * 0
-    z1 = z[:, :1]
-    state0 = (sup0.astype(jnp.int32), mine.astype(jnp.int32), z, z1, z1)
-
-    def round_fn(sup, alive, theta, k, rounds):
-        return kops.fd_round_tip(
-            sup, alive, theta, k, rounds, st_pa, st_pb, st_bf)
-
-    return state0, round_fn
-
-
-def _fd_tip_fused_impl(
-    st_pa: jax.Array,       # (B, L) int32 — partition-local pair lists
-    st_pb: jax.Array,
-    st_bf: jax.Array,       # (B, L) int32 — static pair ⋈ (0 on pad)
-    mine: jax.Array,        # (B, E) bool
-    sup0: jax.Array,        # (B, E) int32
-):
-    """Tip counterpart of :func:`_fd_wing_fused_impl`: one fused Pallas
-    launch per round over the stacked partition-local pair lists.
-    Returns (theta (B, E), rounds (B))."""
-    state0, round_fn = _tip_fused_setup(
-        st_pa, st_pb, st_bf, mine, sup0)
-    out = peelspec._fd_while_fused(state0, round_fn)
-    return out[2], out[4][:, 0]
-
-
-_fd_tip_fused = jax.jit(_fd_tip_fused_impl)
-
-
-def _fd_tip_fused_rings_impl(st_pa, st_pb, st_bf, mine, sup0,
-                             ring_cap: int):
-    """:func:`_fd_tip_fused_impl` + per-round counter rings (obs)."""
-    state0, round_fn = _tip_fused_setup(
-        st_pa, st_pb, st_bf, mine, sup0)
-    out, rings = peelspec._fd_while_fused_rings(state0, round_fn, ring_cap)
-    return out[2], out[4][:, 0], rings
-
-
-_fd_tip_fused_rings = partial(
-    jax.jit, static_argnames=("ring_cap",))(_fd_tip_fused_rings_impl)
-
-
-# =====================================================================
 # Entity-specific per-partition (device) FD bodies
 # =====================================================================
 def _tip_device_update(pa, pb, pbf, n):
@@ -605,14 +492,13 @@ def _fd_wing_compacting(mine, sup0, alive_w0, W0, we1, we2, wp,
     return theta, rounds, nupd
 
 
-def _drain_rings(mode, parts, rounds, rings, cap, cumulative=False):
+def _drain_rings(mode, parts, rounds, rings, cap):
     """Hand one FD launch's counter rings to the active timeline
     collector (no-op when the obs layer is off)."""
     col = obs.active_collector()
     if col is not None:
         col.record_fd_rings(mode, parts, rounds,
-                            [np.asarray(r) for r in rings], cap,
-                            cumulative_updates=cumulative)
+                            [np.asarray(r) for r in rings], cap)
 
 
 def _dense_guard(n_u: int, n_v: int) -> None:
@@ -655,7 +541,6 @@ def tip_decomposition(
     engine: str = "dense",
     fd_driver: str = "device",
     use_pallas: bool = False,
-    fused: bool = False,
     sup0: Optional[np.ndarray] = None,
 ) -> PeelResult:
     """PBNG tip decomposition (§3.2) — θ per U (or V) vertex.
@@ -713,12 +598,6 @@ def tip_decomposition(
     instead of flat segment_sums — θ and round/update counts
     parity-locked either way.
 
-    ``fused`` (csr engine, device/vmapped drivers): run every FD round
-    as ONE fused Pallas launch (``kernels.fd_round``) — k-advance,
-    frontier compaction and the support delta all in-kernel, zero
-    per-round dispatch tail.  θ and round counts bit-identical to the
-    unfused drivers.
-
     ``batch_recount`` (dense engine only): the §5.1 batch optimization
     knob —
       * ``"adaptive"`` (default, paper-faithful): per round, re-count all
@@ -736,7 +615,7 @@ def tip_decomposition(
     spec = build_peel_spec(
         g, "tip", stats, side=side, engine=engine,
         batch_recount=batch_recount, fd_driver=fd_driver,
-        use_pallas=use_pallas, fused=fused, sup0=sup0)
+        use_pallas=use_pallas, sup0=sup0)
     return peelspec.decompose(spec, P, stats, fd_driver=fd_driver)
 
 
@@ -851,7 +730,7 @@ def _tip_fd_peel(
 # =====================================================================
 def _tip_spec_csr(
     gg: BipartiteGraph, stats: PeelStats, use_pallas: bool = False,
-    fused: bool = False, sup0: Optional[np.ndarray] = None,
+    sup0: Optional[np.ndarray] = None,
     wed: Optional[csr.Wedges] = None,
 ) -> PeelSpec:
     """csr-engine tip spec: CD + FD on the U pair list — no dense
@@ -867,13 +746,10 @@ def _tip_spec_csr(
     static because the V side is never peeled, so the engine is purely
     incremental (zero re-counts).  Counts are int32, or int64 under
     ``csr.count_scope`` where ``csr.count_bits`` finds ⋈init above
-    int32; the Pallas routes carry f32/int32 counts and refuse the
+    int32; the Pallas route carries f32/int32 counts and refuses the
     latter.  ``use_pallas`` routes the CD delta through the blocked
     row-sum kernel over the vertex-major slot layout
-    (:func:`csr.tip_delta_slots`).  ``fused`` runs the FD phase through
-    the fused ``kernels.fd_round`` launch (device driver: pack once,
-    slice each partition from the shared stack; vmapped: the whole
-    stack at once)."""
+    (:func:`csr.tip_delta_slots`)."""
     n = gg.n_u
     with obs.span("tip.pairs", cat="init") as sp:
         if wed is None:
@@ -891,10 +767,10 @@ def _tip_spec_csr(
     stats.pair_source, stats.pair_dtype = pairs.source, pairs.dtype
     stats.pair_rows, stats.pair_k = pairs.rows, pairs.k
     stats.count_bits = bits
-    if bits == 64 and (use_pallas or fused):
+    if bits == 64 and use_pallas:
         raise OverflowError(
-            "tip supports exceed int32 and the Pallas tip kernels carry "
-            "f32/int32 counts; run without use_pallas/fused, whose "
+            "tip supports exceed int32 and the Pallas tip kernel carries "
+            "f32/int32 counts; run without use_pallas, whose "
             "segment_sum path widens them to int64")
     cdt = np.int64 if bits == 64 else np.int32
     scope = partial(csr.count_scope, bits)
@@ -927,46 +803,7 @@ def _tip_spec_csr(
             )
         return out
 
-    # fused device driver: pack the partition stack ONCE (lazily, on the
-    # first fd_partition call — part/sup_init are fixed for the whole FD
-    # phase), then slice each partition as a B=1 batch into the same
-    # jitted fused entry.  One compile for every partition (shared
-    # Emax/Lmax buckets), bit-identical to the unfused cascade.
-    fused_pack: dict = {}
-
     def fd_partition(i, part, sup_init, theta, fd_driver):
-        if fused and fd_driver == "device":
-            from repro.kernels import ops as kops
-
-            with obs.span("fd.pack", cat="fd.pack"):
-                if "p" not in fused_pack:
-                    from .distributed import pack_fd_partitions_tip_csr
-
-                    fused_pack["p"] = pack_fd_partitions_tip_csr(
-                        pairs, pair_bf0, part, sup_init,
-                        int(part.max()) + 1 if part.size else 0,
-                        bucket=True, stacked=True,
-                    )
-                p = fused_pack["p"]
-                f_args = (
-                    jnp.asarray(p["st_pa"][i:i + 1]),
-                    jnp.asarray(p["st_pb"][i:i + 1]),
-                    jnp.asarray(p["st_bf"][i:i + 1]),
-                    jnp.asarray(p["mine"][i:i + 1]),
-                    jnp.asarray(p["sup0"][i:i + 1]),
-                )
-            cap = obs.fd_ring_cap()
-            if cap:
-                theta_st, rounds, rings = _fd_tip_fused_rings(
-                    *f_args, ring_cap=cap)
-                _drain_rings("fused", [i], [int(rounds[0])], rings, cap,
-                             cumulative=True)
-            else:
-                theta_st, rounds = _fd_tip_fused(*f_args)
-            mm = p["mine"][i]
-            theta[p["gids"][i][mm]] = (
-                np.asarray(theta_st[0]).astype(np.int64)[mm])
-            return int(rounds[0]), 0, 0
         with scope():
             rounds = _tip_fd_csr(pairs, pair_bf0, part, i, sup_init, theta,
                                  fd_driver=fd_driver, dtype=cdt)
@@ -976,7 +813,7 @@ def _tip_spec_csr(
         with scope():
             rounds = _tip_fd_vmapped_csr(
                 pairs, pair_bf0, part, sup_init, theta, n_parts,
-                fused=fused, dtype=cdt)
+                dtype=cdt)
         return rounds, 0
 
     return PeelSpec(
@@ -1076,63 +913,33 @@ def _tip_fd_vmapped_csr(
     sup_init: np.ndarray,
     theta: np.ndarray,
     n_parts: int,
-    fused: bool = False,
     dtype=np.int32,
 ) -> np.ndarray:
     """Single-dispatch tip Phase 2: pack all partitions into one stacked
     shape-bucketed layout and peel them in ONE batched while_loop
     (:func:`_fd_tip_vmapped`).  Writes θ in place; returns the (B,)
     per-partition round counts (bit-identical to the per-partition
-    drivers — same cascade, one dispatch).
-
-    ``fused=True`` swaps the segment-sum round body for the fused
-    ``kernels.fd_round`` launch over the stacked partition-local pair
-    lists (:func:`_fd_tip_fused_impl`) — one Pallas call per round and
-    nothing else."""
+    drivers — same cascade, one dispatch)."""
     if n_parts == 0:
         return np.zeros(0, dtype=np.int64)
     from .distributed import pack_fd_partitions_tip_csr
 
     packed = pack_fd_partitions_tip_csr(
-        wed, pair_bf0, part, sup_init, n_parts, bucket=True, stacked=fused,
-        dtype=dtype,
-    )
+        wed, pair_bf0, part, sup_init, n_parts, bucket=True, dtype=dtype)
+    args = tuple(jnp.asarray(packed[k])
+                 for k in ("pa", "pb", "bf", "mine", "sup0"))
     cap = obs.fd_ring_cap()
-    if fused:
-        from repro.kernels import ops as kops
-
-        if cap:
-            theta_st, rounds, rings = _fd_tip_fused_rings(
-                jnp.asarray(packed["st_pa"]), jnp.asarray(packed["st_pb"]),
-                jnp.asarray(packed["st_bf"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]), ring_cap=cap,
-            )
-        else:
-            theta_st, rounds = _fd_tip_fused(
-                jnp.asarray(packed["st_pa"]), jnp.asarray(packed["st_pb"]),
-                jnp.asarray(packed["st_bf"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]),
-            )
+    if cap:
+        theta_st, rounds, _, rings = _fd_tip_vmapped_rings(
+            *args, ring_cap=cap)
     else:
-        if cap:
-            theta_st, rounds, _, rings = _fd_tip_vmapped_rings(
-                jnp.asarray(packed["pa"]), jnp.asarray(packed["pb"]),
-                jnp.asarray(packed["bf"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]), ring_cap=cap,
-            )
-        else:
-            theta_st, rounds, _ = _fd_tip_vmapped(
-                jnp.asarray(packed["pa"]), jnp.asarray(packed["pb"]),
-                jnp.asarray(packed["bf"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]),
-            )
+        theta_st, rounds, _ = _fd_tip_vmapped(*args)
     mm = packed["mine"]
     theta[packed["gids"][mm]] = np.asarray(theta_st).astype(np.int64)[mm]
     rounds_np = np.asarray(rounds).astype(np.int64)
     if cap:
-        _drain_rings("fused" if fused else "vmapped",
-                     list(range(rounds_np.size)), rounds_np.tolist(),
-                     rings, cap, cumulative=fused)
+        _drain_rings("vmapped", list(range(rounds_np.size)),
+                     rounds_np.tolist(), rings, cap)
     return rounds_np
 
 
@@ -1143,30 +950,25 @@ def _wing_fd_vmapped_csr(
     theta: np.ndarray,
     n_parts: int,
     use_pallas: bool = False,
-    fused: bool = False,
 ) -> Tuple[np.ndarray, int]:
     """Single-dispatch wing Phase 2 (see :func:`_tip_fd_vmapped_csr`).
 
     ``use_pallas`` swaps the vmapped segment-sum body for the blocked
     Pallas ``support_update`` kernel over the stacked slot layout
     (:func:`_fd_wing_vmapped_pallas`) — interpret mode off-TPU, θ and
-    round/update counts parity-locked either way.  ``fused`` goes one
-    further: the ENTIRE round body (k-advance + compaction + support
-    update + loss scatter) is one ``kernels.fd_round`` launch
-    (:func:`_fd_wing_fused_impl`).  Returns (rounds (B,), update
-    count)."""
+    round/update counts parity-locked either way.  Returns (rounds (B,),
+    update count)."""
     if n_parts == 0:
         return np.zeros(0, dtype=np.int64), 0
     from .distributed import pack_fd_partitions_csr
 
-    slotted = use_pallas or fused
     packed = pack_fd_partitions_csr(
         wed, part, sup_init, n_parts, bucket=True,
-        flat=not slotted, slots=slotted,
+        flat=not use_pallas, slots=use_pallas,
     )
     cap = obs.fd_ring_cap()
     rings = None
-    if slotted:
+    if use_pallas:
         from repro.kernels import ops as kops  # local: keep core light
 
         R, _ = packed["slot_sizes"]
@@ -1174,55 +976,34 @@ def _wing_fd_vmapped_csr(
         W_rows = np.zeros((n_parts, R), dtype=np.int32)
         w = min(R, W0.shape[1])
         W_rows[:, :w] = W0[:, :w]
-        # the fused kernel picks its own (interpret-only) mode
-        kw = {} if fused else dict(interpret=kops.default_interpret())
+        args = (jnp.asarray(packed["slot_e1"]),
+                jnp.asarray(packed["slot_e2"]),
+                jnp.asarray(packed["slot_valid"]), jnp.asarray(W_rows),
+                jnp.asarray(packed["mine"]), jnp.asarray(packed["sup0"]))
+        interpret = kops.default_interpret()
         if cap:
-            body = (_fd_wing_fused_rings if fused
-                    else _fd_wing_vmapped_pallas_rings)
-            theta_st, rounds, nupd, rings = body(
-                jnp.asarray(packed["slot_e1"]),
-                jnp.asarray(packed["slot_e2"]),
-                jnp.asarray(packed["slot_valid"]), jnp.asarray(W_rows),
-                jnp.asarray(packed["mine"]), jnp.asarray(packed["sup0"]),
-                ring_cap=cap, **kw,
-            )
+            theta_st, rounds, nupd, rings = _fd_wing_vmapped_pallas_rings(
+                *args, interpret=interpret, ring_cap=cap)
         else:
-            body = _fd_wing_fused if fused else _fd_wing_vmapped_pallas
-            theta_st, rounds, nupd = body(
-                jnp.asarray(packed["slot_e1"]),
-                jnp.asarray(packed["slot_e2"]),
-                jnp.asarray(packed["slot_valid"]), jnp.asarray(W_rows),
-                jnp.asarray(packed["mine"]), jnp.asarray(packed["sup0"]),
-                **kw,
-            )
+            theta_st, rounds, nupd = _fd_wing_vmapped_pallas(
+                *args, interpret=interpret)
     else:
+        args = tuple(jnp.asarray(packed[k]) for k in (
+            "flat_we1", "flat_we2", "flat_wp", "flat_alive0", "flat_W0",
+            "mine", "sup0"))
+        n_pairs = int(packed["flat_W0"].shape[0])
         if cap:
             theta_st, rounds, nupd, rings = _fd_wing_vmapped_rings(
-                jnp.asarray(packed["flat_we1"]),
-                jnp.asarray(packed["flat_we2"]),
-                jnp.asarray(packed["flat_wp"]),
-                jnp.asarray(packed["flat_alive0"]),
-                jnp.asarray(packed["flat_W0"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]),
-                n_pairs=int(packed["flat_W0"].shape[0]), ring_cap=cap,
-            )
+                *args, n_pairs=n_pairs, ring_cap=cap)
         else:
             theta_st, rounds, nupd = _fd_wing_vmapped(
-                jnp.asarray(packed["flat_we1"]),
-                jnp.asarray(packed["flat_we2"]),
-                jnp.asarray(packed["flat_wp"]),
-                jnp.asarray(packed["flat_alive0"]),
-                jnp.asarray(packed["flat_W0"]), jnp.asarray(packed["mine"]),
-                jnp.asarray(packed["sup0"]),
-                n_pairs=int(packed["flat_W0"].shape[0]),
-            )
+                *args, n_pairs=n_pairs)
     mm = packed["mine"]
     theta[packed["gids"][mm]] = np.asarray(theta_st).astype(np.int64)[mm]
     rounds_np = np.asarray(rounds).astype(np.int64)
     if rings is not None:
-        _drain_rings("fused" if fused else "vmapped",
-                     list(range(rounds_np.size)), rounds_np.tolist(),
-                     rings, cap, cumulative=fused)
+        _drain_rings("vmapped", list(range(rounds_np.size)),
+                     rounds_np.tolist(), rings, cap)
     return rounds_np, int(nupd)
 
 
@@ -1291,7 +1072,6 @@ def wing_decomposition(
     be: Optional[BEIndex] = None,
     fd_driver: str = "device",
     use_pallas: bool = False,
-    fused: bool = False,
     sup0: Optional[np.ndarray] = None,
 ) -> PeelResult:
     """PBNG wing decomposition (§3.3) — θ per edge.
@@ -1331,20 +1111,14 @@ def wing_decomposition(
     slot layout (interpret mode off-TPU) instead of flat segment_sums.
     With ``fd_driver="vmapped"`` the same kernel also runs INSIDE the FD
     while_loop body over the stacked partition slot layout (one kernel
-    launch per round covering every partition).
-
-    ``fused`` (csr engine, device/vmapped drivers): fuse the ENTIRE FD
-    round body — k-advance, frontier compaction, widow/survivor support
-    update and loss scatter — into one ``kernels.fd_round`` Pallas
-    launch, so a round is a single kernel dispatch and nothing else.  θ
-    and round/update counts bit-identical to the unfused drivers."""
+    launch per round covering every partition)."""
     stats = PeelStats(
         engine=engine,
         fd_driver=fd_driver if engine == "csr" else "host",
     )
     spec = build_peel_spec(
         g, "wing", stats, engine=engine, be=be, fd_driver=fd_driver,
-        use_pallas=use_pallas, fused=fused, sup0=sup0)
+        use_pallas=use_pallas, sup0=sup0)
     return peelspec.decompose(spec, P, stats, fd_driver=fd_driver)
 
 
@@ -1428,14 +1202,13 @@ def _wing_spec_dense(
 
 def _wing_spec_csr(
     g: BipartiteGraph, stats: PeelStats, use_pallas: bool = False,
-    fused: bool = False, sup0: Optional[np.ndarray] = None,
+    sup0: Optional[np.ndarray] = None,
     wed: Optional[csr.Wedges] = None,
 ) -> PeelSpec:
     """csr wing spec: incremental wedge-list widow/survivor updates as
     the CD step (optionally through the blocked Pallas kernel on the
     pairs-major slot layout), touching-wedge packed lists as the FD
-    rule.  ``fused`` routes the FD phase through the fused
-    ``kernels.fd_round`` launch (see :func:`_fd_wing_fused_impl`)."""
+    rule."""
     m = g.m
     if wed is None:
         wed = csr.build_wedges(g)
@@ -1478,58 +1251,14 @@ def _wing_spec_csr(
         stats.updates += int(nupd)
         return np.rint(np.asarray(state["support"])).astype(np.int64)
 
-    # fused device driver: one lazy pack of the full partition stack,
-    # each partition sliced as a B=1 batch into the shared jitted fused
-    # entry (same bucketed shapes → one compile for all partitions)
-    fused_pack: dict = {}
-
     def fd_partition(i, part, sup_init, theta, fd_driver):
-        if fused and fd_driver == "device":
-            from repro.kernels import ops as kops
-
-            with obs.span("fd.pack", cat="fd.pack"):
-                if "p" not in fused_pack:
-                    from .distributed import pack_fd_partitions_csr
-
-                    n_parts = int(part.max()) + 1 if part.size else 0
-                    p = pack_fd_partitions_csr(
-                        wed, part, sup_init, n_parts, bucket=True,
-                        slots=True)
-                    R, _ = p["slot_sizes"]
-                    W_rows = np.zeros((n_parts, R), dtype=np.int32)
-                    w = min(R, p["W0"].shape[1])
-                    W_rows[:, :w] = p["W0"][:, :w]
-                    p["W_rows"] = W_rows
-                    fused_pack["p"] = p
-                p = fused_pack["p"]
-                f_args = (
-                    jnp.asarray(p["slot_e1"][i:i + 1]),
-                    jnp.asarray(p["slot_e2"][i:i + 1]),
-                    jnp.asarray(p["slot_valid"][i:i + 1]),
-                    jnp.asarray(p["W_rows"][i:i + 1]),
-                    jnp.asarray(p["mine"][i:i + 1]),
-                    jnp.asarray(p["sup0"][i:i + 1]),
-                )
-            cap = obs.fd_ring_cap()
-            if cap:
-                theta_st, rounds, nupd, rings = _fd_wing_fused_rings(
-                    *f_args, ring_cap=cap)
-                _drain_rings("fused", [i], [int(rounds[0])], rings, cap,
-                             cumulative=True)
-            else:
-                theta_st, rounds, nupd = _fd_wing_fused(*f_args)
-            mm = p["mine"][i]
-            theta[p["gids"][i][mm]] = (
-                np.asarray(theta_st[0]).astype(np.int64)[mm])
-            return int(rounds[0]), int(nupd), 0
         rounds, nupd = _wing_fd_csr(
             wed, part, i, sup_init, theta, fd_driver=fd_driver)
         return rounds, nupd, 0
 
     def fd_vmapped(part, sup_init, theta, n_parts):
         return _wing_fd_vmapped_csr(
-            wed, part, sup_init, theta, n_parts, use_pallas=use_pallas,
-            fused=fused)
+            wed, part, sup_init, theta, n_parts, use_pallas=use_pallas)
 
     workload, est = _wing_workload_est()
     return PeelSpec(

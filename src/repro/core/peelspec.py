@@ -621,30 +621,6 @@ def _fd_while_vmapped(mine: jax.Array, sup0: jax.Array, update, aux):
     return theta, rounds, nupd
 
 
-def _fd_while_fused(state0, round_fn):
-    """The zero-per-round-dispatch FD driver: the whole cascade is one
-    ``lax.while_loop`` whose body is ONE fused Pallas round
-    (``kernels.fd_round`` — k-advance, frontier compaction and support
-    update all in-kernel), so a round's jaxpr is a single ``pallas_call``
-    with no segment-sum / argmin / compaction tail.
-
-    ``state0`` is the loop-carried tuple with the alive mask (any dtype,
-    nonzero = alive) at index 1; ``round_fn(*state) -> state`` must be
-    the fused round.  Loop-invariant operands (slot layouts, pair lists)
-    stay closed over inside ``round_fn`` — they never enter the carry.
-    Semantics (k-advance, per-partition round counts, θ) are
-    bit-identical to :func:`_fd_while_vmapped` / :func:`_fd_while_device`
-    (golden- and property-locked in ``tests/test_fused_fd.py``)."""
-
-    def cond(state):
-        return jnp.any(state[1] != 0)
-
-    def body(state):
-        return round_fn(*state)
-
-    return jax.lax.while_loop(cond, body, state0)
-
-
 # =====================================================================
 # Telemetry-ON twins of the FD cascade drivers (obs counter rings)
 # =====================================================================
@@ -743,44 +719,3 @@ def _fd_while_vmapped_rings(mine: jax.Array, sup0: jax.Array, update, aux,
                            jnp.zeros((cap,), jnp.int32)))
     out = jax.lax.while_loop(cond, body, init)
     return out[3], out[5], out[6], out[8]
-
-
-def _fd_while_fused_rings(state0, round_fn, ring_cap: int):
-    """:func:`_fd_while_fused` + counter rings derived OUTSIDE the
-    fused round (the Pallas kernel itself is untouched): died/frontier
-    from the alive mask (state index 1, nonzero = alive) before/after
-    the round, k from state index 3, and — when the state carries a
-    per-partition update count at index 5 (the wing 8-tuple) — the ring
-    stores its *cumulative* value per round (the drain converts to
-    deltas via ``cumulative_updates=True``).  Returns
-    ``(state, (died, frontier, k, upd_cum))``, rings ``(ring_cap, B)``.
-    """
-    cap = int(ring_cap)
-    B = state0[1].shape[0]
-
-    def cond(carry):
-        state, _, _ = carry
-        return jnp.any(state[1] != 0)
-
-    def body(carry):
-        state, it, rings = carry
-        died_r, fr_r, k_r, nu_r = rings
-        alive_before = jnp.sum((state[1] != 0).astype(jnp.int32), axis=1)
-        new = round_fn(*state)
-        alive_after = jnp.sum((new[1] != 0).astype(jnp.int32), axis=1)
-        k_now = new[3][:, 0].astype(jnp.int32)
-        nu_cum = (jnp.sum(new[5], axis=1).astype(jnp.int32)
-                  if len(new) > 5 else jnp.zeros((B,), jnp.int32))
-        slot = jnp.minimum(it, cap - 1)
-        rings = (
-            died_r.at[slot].set(alive_before - alive_after),
-            fr_r.at[slot].set(alive_after),
-            k_r.at[slot].set(k_now),
-            nu_r.at[slot].set(nu_cum),
-        )
-        return (new, it + 1, rings)
-
-    zrow = jnp.zeros((cap, B), jnp.int32)
-    state, _, rings = jax.lax.while_loop(
-        cond, body, (state0, jnp.int32(0), (zrow, zrow, zrow, zrow)))
-    return state, rings

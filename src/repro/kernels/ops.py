@@ -20,7 +20,6 @@ from .butterfly_count import (
     vertex_count_pallas,
     vertex_count_tile_pallas,
 )
-from .fd_round import MOSAIC_LIMITS, fd_round_tip_pallas, fd_round_wing_pallas
 from .flash_attention import flash_attention_pallas
 from .support_update import support_update_pallas
 from .wedge_count import wedge_count_pallas, wedge_count_tile_pallas
@@ -30,8 +29,6 @@ __all__ = [
     "vertex_butterflies_tiled",
     "edge_wedge_matrix",
     "bloom_update",
-    "fd_round_tip",
-    "fd_round_wing",
     "flash_attention",
     "pack_blooms",
     "pair_wedge_counts",
@@ -273,48 +270,6 @@ def support_update(
         bp=bp, bk=bk, interpret=interpret,
     )
     return c1[:n, :kdim], c2[:n, :kdim], c[:n]
-
-
-def _refuse_on_tpu() -> None:
-    """The fused FD round runs in interpret mode off a TPU, or not at
-    all: it has no Mosaic lowering and interpret mode is refused on a
-    TPU backend."""
-    if jax.default_backend() == "tpu":
-        raise NotImplementedError(
-            f"the fused FD round kernel cannot run on a TPU: {MOSAIC_LIMITS}")
-
-
-# The fd_round wrappers are deliberately NOT jitted: they only ever run
-# inside an already-jitted while_loop body (``peelspec._fd_while_fused``
-# consumers), where a nested pjit would wrap the pallas_call and obscure
-# the round body's jaxpr — tests assert that body is exactly ONE
-# pallas_call and nothing else (tests/test_fused_fd.py).
-def fd_round_wing(sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2):
-    """One fused wing-FD round (k-advance + frontier compaction + widow/
-    survivor support update) as a single Pallas launch.
-
-    State in/out (same order): sup/alive/theta (B, E) i32, k/rounds/
-    nupd (B, 1) i32, wedge-slot alive (B, R, K) i32, W (B, R) f32.
-    ``e1``/``e2`` are the static (B, R, K) local edge ids with sentinel
-    E (``distributed._pack_fd_slots_csr``).  Interpret mode only: the
-    kernel has no Mosaic lowering (``fd_round.MOSAIC_LIMITS``)."""
-    _refuse_on_tpu()
-    return fd_round_wing_pallas(
-        sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2,
-        interpret=True)
-
-
-def fd_round_tip(sup, alive, theta, k, rounds, pa, pb, bf):
-    """One fused tip-FD round as a single Pallas launch.
-
-    State in/out (same order): sup/alive/theta (B, E) i32, k/rounds
-    (B, 1) i32.  ``pa``/``pb``/``bf`` are the static (B, L) partition-
-    local pair lists (``pack_fd_partitions_tip_csr(stacked=True)``;
-    bf=0 padding is algebra-neutral).  Interpret mode only, like
-    :func:`fd_round_wing`."""
-    _refuse_on_tpu()
-    return fd_round_tip_pallas(
-        sup, alive, theta, k, rounds, pa, pb, bf, interpret=True)
 
 
 def pack_blooms(

@@ -11,8 +11,6 @@ __all__ = [
     "flash_attention_ref",
     "pair_wedge_counts_ref",
     "support_update_ref",
-    "fd_round_wing_ref",
-    "fd_round_tip_ref",
 ]
 
 
@@ -44,87 +42,6 @@ def support_update_ref(pe1, pe2, alive, W):
         (1.0 - pe2) * widow + surv_loss,
         c,
     )
-
-
-def _fd_advance_ref(sup, alive, theta, k):
-    """Batched k-advance + frontier compaction shared by both fused-round
-    oracles — the ``peelspec._fd_while_vmapped`` body prologue."""
-    big = jnp.iinfo(jnp.int32).max
-    live = jnp.any(alive, axis=1)
-    k = jnp.maximum(k[:, 0], jnp.min(jnp.where(alive, sup, big), axis=1))
-    S = alive & (sup <= k[:, None])
-    theta = jnp.where(S, k[:, None], theta)
-    return S, alive & ~S, theta, k[:, None], live
-
-
-def fd_round_wing_ref(sup, alive, theta, k, rounds, nupd, aslot, W, e1, e2):
-    """Oracle for the fused wing-FD round kernel, batched over the
-    leading partition axis.
-
-    Same state threading as ``fd_round_wing_pallas``: sup/alive/theta
-    (B, E), k/rounds/nupd (B, 1), wedge slots (B, R, K) with sentinel
-    edge id E, W (B, R).  Pure jnp — the k-advance/compaction prologue
-    followed by ``support_update_ref``'s widow/survivor algebra and a
-    segment-sum loss scatter."""
-    alive = alive != 0
-    aslot = aslot != 0
-    S, alive, theta, k, live = _fd_advance_ref(sup, alive, theta, k)
-
-    B, E = sup.shape
-    S_pad = jnp.concatenate([S, jnp.zeros((B, 1), bool)], axis=1)
-    pe1 = jnp.take_along_axis(S_pad, e1.reshape(B, -1), axis=1).reshape(
-        e1.shape)
-    pe2 = jnp.take_along_axis(S_pad, e2.reshape(B, -1), axis=1).reshape(
-        e2.shape)
-    # support_update_ref's widow/survivor algebra, batched over (B, R, K)
-    dies = aslot & (pe1 | pe2)
-    c_row = jnp.sum(dies.astype(jnp.float32), axis=2)
-    surv = aslot & ~dies
-    wm1 = (W.astype(jnp.float32) - 1.0)[:, :, None]
-    surv_c = jnp.where(surv, c_row[:, :, None], 0.0)
-    c1 = jnp.rint(
-        jnp.where(dies & ~pe1, wm1, 0.0) + surv_c).astype(jnp.int32)
-    c2 = jnp.rint(
-        jnp.where(dies & ~pe2, wm1, 0.0) + surv_c).astype(jnp.int32)
-    ci = jnp.rint(c_row).astype(jnp.int32)
-
-    off = (jnp.arange(B, dtype=jnp.int32) * (E + 1))[:, None, None]
-    loss = jax.ops.segment_sum(
-        c1.reshape(-1), (e1 + off).reshape(-1), num_segments=B * (E + 1)
-    ) + jax.ops.segment_sum(
-        c2.reshape(-1), (e2 + off).reshape(-1), num_segments=B * (E + 1)
-    )
-    loss = loss.reshape(B, E + 1)[:, :E]
-    nu = jnp.sum(
-        (dies & (~pe1 | ~pe2)).astype(jnp.int32), axis=(1, 2)
-    ) + jnp.sum((surv & (ci[:, :, None] > 0)).astype(jnp.int32), axis=(1, 2))
-    return (sup - loss, alive.astype(jnp.int32), theta, k,
-            rounds + live.astype(jnp.int32)[:, None],
-            nupd + nu[:, None], surv.astype(jnp.int32),
-            W.astype(jnp.float32) - c_row)
-
-
-def fd_round_tip_ref(sup, alive, theta, k, rounds, pa, pb, bf):
-    """Oracle for the fused tip-FD round kernel (batched): the k-advance
-    prologue plus the static pair-butterfly delta of
-    ``core.csr.tip_delta_csr`` over partition-local pair lists."""
-    alive = alive != 0
-    S, alive, theta, k, live = _fd_advance_ref(sup, alive, theta, k)
-    B, E = sup.shape
-    off = (jnp.arange(B, dtype=jnp.int32) * E)[:, None]
-    Sf = S.reshape(-1)
-    pag = (pa + off).reshape(-1)
-    pbg = (pb + off).reshape(-1)
-    loss = (
-        jax.ops.segment_sum(
-            jnp.where(Sf[pbg], bf.reshape(-1), 0), pag,
-            num_segments=B * E)
-        + jax.ops.segment_sum(
-            jnp.where(Sf[pag], bf.reshape(-1), 0), pbg,
-            num_segments=B * E)
-    ).reshape(B, E)
-    return (sup - loss, alive.astype(jnp.int32), theta, k,
-            rounds + live.astype(jnp.int32)[:, None])
 
 
 def vertex_butterflies_ref(A: jax.Array) -> jax.Array:

@@ -26,7 +26,7 @@ class LaunchError(SystemExit):
         super().__init__(f"[peel] error: {msg}")
 
 
-def _validate(args, n_dev: int, platform: str) -> None:
+def _validate(args, n_dev: int) -> None:
     """Resolve the per-kind engine default, then reject unsupported
     flag combinations with explicit errors."""
     if args.engine is None:
@@ -63,25 +63,7 @@ def _validate(args, n_dev: int, platform: str) -> None:
             "--aligned is the one-psum CD sharding (csr: pair/vertex "
             "aligned; beindex: bloom aligned); --engine dense has no "
             "sharded index to align")
-    if args.fused_fd and platform == "tpu":
-        from repro.kernels.fd_round import MOSAIC_LIMITS
-        raise LaunchError(
-            f"--fused-fd on a TPU backend: {MOSAIC_LIMITS}.  The default "
-            "(unfused) device FD path runs on the chip")
-    if args.fused_fd and args.engine != "csr":
-        raise LaunchError(
-            "--fused-fd is the fused csr FD round kernel; pass "
-            "--engine csr")
-    if args.fused_fd and args.fd_driver == "host":
-        raise LaunchError(
-            "--fused-fd fuses the device-side FD round; the host driver "
-            "has no device round body (pass --fd-driver device|vmapped)")
     if n_dev > 1:
-        if args.fused_fd:
-            raise LaunchError(
-                "--fused-fd is wired for the single-device csr FD "
-                "drivers; distributed FD runs per-partition while_loops "
-                "under shard_map")
         if args.kind == "wing" and args.engine == "dense":
             raise LaunchError(
                 "no distributed dense wing path; pass --engine "
@@ -305,33 +287,6 @@ def _dryrun() -> int:
     print("[peel-dryrun] vmapped tip FD: whole Phase 2 is ONE while_loop, "
           "zero collectives ✓")
 
-    # --- fused FD (single device): the while_loop ROUND BODY must be
-    # exactly ONE pallas_call — no segment-sum/argmin/compaction tail
-    from repro.core.peel import _fd_wing_fused_impl
-
-    packed_f = D.pack_fd_partitions_csr(
-        wed, res_c.part, res_c.support_init, res_c.stats.p_effective,
-        bucket=True, slots=True)
-    R_f, _ = packed_f["slot_sizes"]
-    B_f = packed_f["sup0"].shape[0]
-    W_rows = np.zeros((B_f, R_f), np.int32)
-    w_f = min(R_f, packed_f["W0"].shape[1])
-    W_rows[:, :w_f] = packed_f["W0"][:, :w_f]
-    fj = jax.make_jaxpr(_fd_wing_fused_impl)(
-        jnp.asarray(packed_f["slot_e1"]), jnp.asarray(packed_f["slot_e2"]),
-        jnp.asarray(packed_f["slot_valid"]), jnp.asarray(W_rows),
-        jnp.asarray(packed_f["mine"]), jnp.asarray(packed_f["sup0"]))
-    whiles = [e for e in fj.jaxpr.eqns if e.primitive.name == "while"]
-    assert len(whiles) == 1, f"fused FD must be ONE while_loop, {len(whiles)}"
-    body_prims = [e.primitive.name
-                  for e in whiles[0].params["body_jaxpr"].jaxpr.eqns]
-    assert body_prims.count("pallas_call") == 1, body_prims
-    banned_f = {"scatter", "scatter-add", "scatter_add", "gather",
-                "argmin", "reduce_min", "cumsum", "sort", "segment_sum"}
-    assert not banned_f & set(body_prims), body_prims
-    print("[peel-dryrun] fused FD round body is ONE pallas_call "
-          f"(body prims: {body_prims}) ✓")
-
     # --- hierarchical CD at 512 devices: the ONE logical psum staged
     # over a (16, 32) 2-D mesh — exactly two all-reduces with nested
     # replica groups, bit-identical int32 reduction
@@ -403,7 +358,7 @@ def run(args) -> dict:
     from repro.launch.mesh import make_peel_mesh
 
     n_dev = len(jax.devices())
-    _validate(args, n_dev, jax.default_backend())
+    _validate(args, n_dev)
 
     sup0 = None
     if args.edges:
@@ -454,7 +409,7 @@ def run(args) -> dict:
             res = wing_decomposition(
                 g, P=args.parts, engine=args.engine,
                 fd_driver=args.fd_driver, use_pallas=args.use_pallas,
-                fused=args.fused_fd, sup0=sup0)
+                sup0=sup0)
             result = res
             theta = res.theta
             s = res.stats
@@ -475,7 +430,7 @@ def run(args) -> dict:
             res = tip_decomposition(
                 g, side=args.side, P=args.parts, engine=args.engine,
                 fd_driver=args.fd_driver, use_pallas=args.use_pallas,
-                fused=args.fused_fd, sup0=sup0)
+                sup0=sup0)
             result = res
             theta = res.theta
             s = res.stats
@@ -555,13 +510,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "pair-aligned wedges; tip csr: vertex-aligned "
                          "pair entries; wing beindex: bloom-aligned "
                          "links)")
-    ap.add_argument("--fused-fd", action="store_true",
-                    help="csr engines, single device: run every FD round "
-                         "as ONE fused Pallas launch (kernels.fd_round) "
-                         "— k-advance + compaction + support update "
-                         "in-kernel.  CPU interpret mode only: the kernel "
-                         "has no Mosaic lowering, so a TPU backend "
-                         "refuses it")
     ap.add_argument("--use-pallas", action="store_true",
                     help="csr engines only: run CD support updates "
                          "through the blocked Pallas kernels (and, for "

@@ -49,8 +49,8 @@ def _validate(args) -> None:
         raise LaunchError(
             "streaming supports the per-partition fd_drivers (device/"
             "host — dirty partitions re-run alone) and vmapped (the "
-            "whole Phase 2 redispatches as its one batched loop); "
-            "fused is not wired — pass --fd-driver device|host|vmapped")
+            "whole Phase 2 redispatches as its one batched loop) — "
+            "pass --fd-driver device|host|vmapped")
     if args.fd_driver == "vmapped" and args.engine != "csr":
         raise LaunchError(
             "fd_driver='vmapped' is the csr single-dispatch Phase 2 — "

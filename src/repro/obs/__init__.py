@@ -12,9 +12,9 @@ Three parts (see docs/OBSERVABILITY.md):
 
 The whole layer is gated by :func:`enable` / :func:`disable`.  **Off
 (the default) is zero-overhead**: no ring code is traced, so every
-structural jaxpr invariant (single-``while`` FD, one-``pallas_call``
-fused body, one-psum CD, loop-free dispatch) sees the byte-identical
-program — asserted against ``tests/goldens/obs_jaxprs.json``.
+structural jaxpr invariant (single-``while`` FD, one-psum CD, loop-free
+dispatch) sees the byte-identical program — asserted against
+``tests/goldens/obs_jaxprs.json``.
 ``enable(timeline=False)`` turns on spans and counters alone: no
 collector, no ring code, the same device programs as off.
 

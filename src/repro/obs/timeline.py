@@ -9,7 +9,7 @@ Two sources feed a :class:`PeelTimeline`:
   by ``cd_step``.
 
 * **FD rounds** run inside a single device-resident ``while_loop`` (one
-  per partition, or ONE for the whole vmapped/fused Phase 2), invisible
+  per partition, or ONE for the whole vmapped Phase 2), invisible
   to the host.  The telemetry-on twins of the FD drivers
   (``peelspec._fd_while_*_rings``) thread preallocated int32 **counter
   rings** through the loop carry — per-round dying count, frontier
@@ -55,7 +55,7 @@ class PeelTimeline:
 
     ``fd``: one dict per FD launch::
 
-        {"mode": "device"|"vmapped"|"fused"|"host",
+        {"mode": "device"|"vmapped"|"host",
          "parts": [int, ...],          # partitions covered (len B)
          "rounds": [int, ...],         # per-partition round count (len B)
          "died": (T, B) int array,     # per recorded iteration
@@ -186,36 +186,24 @@ class TimelineCollector:
 
     # -- FD ring drains ----------------------------------------------
     def record_fd_rings(self, mode: str, parts: Sequence[int],
-                        rounds: Sequence[int], rings: Any, cap: int,
-                        cumulative_updates: bool = False) -> None:
+                        rounds: Sequence[int], rings: Any, cap: int) -> None:
         """Drain one launch's counter rings.
 
         ``rings`` is the carry tail returned by a ``*_rings`` FD driver:
         ``(died, frontier, k, updates)`` device arrays shaped ``(cap,)``
-        (device driver) or ``(cap, B)`` / ``(cap,)`` for the update ring
-        (vmapped / fused).  ``cumulative_updates=True`` marks rings that
-        store the running per-partition update total (the fused wing
-        kernel's state carries cumulative ``nupd``); the drain converts
-        them to per-iteration deltas.
+        (device driver) or ``(cap, B)`` with a ``(cap,)`` update ring
+        (vmapped).
         """
         died, frontier, k, upd = (np.asarray(r) for r in rings[:4])
         if died.ndim == 1:                       # device driver: B == 1
             died, frontier, k = (a[:, None] for a in (died, frontier, k))
-            if upd.ndim == 1 and cumulative_updates:
-                upd = upd[:, None]
         rounds = [int(r) for r in rounds]
         n = min(max(rounds, default=0), int(cap))
         died, frontier, k = died[:n], frontier[:n], k[:n]
-        updates: Optional[np.ndarray]
-        if cumulative_updates:
-            per_part = np.diff(upd[:n], axis=0, prepend=0)
-            updates = per_part.sum(axis=1).astype(np.int64)
-        else:
-            updates = upd[:n].astype(np.int64)
         self.fd_launches.append(dict(
             mode=mode, parts=[int(p) for p in parts], rounds=rounds,
             died=died.astype(np.int64), frontier=frontier.astype(np.int64),
-            k=k.astype(np.int64), updates=updates,
+            k=k.astype(np.int64), updates=upd[:n].astype(np.int64),
             truncated=max(rounds, default=0) > int(cap)))
 
     def record_fd_counts(self, mode: str, parts: Sequence[int],

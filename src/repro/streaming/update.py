@@ -76,8 +76,7 @@ class StreamConfig:
                 "streaming fd_driver must be 'device' | 'host' "
                 "(per-partition, localized to dirty partitions) or "
                 "'vmapped' (csr, single-device: one batched Phase-2 "
-                "launch per epoch); the fused driver has no streaming "
-                "entry")
+                "launch per epoch)")
         if self.fd_driver == "vmapped":
             if self.engine != "csr":
                 raise ValueError(

@@ -8,17 +8,20 @@ trace-time branch that, when disabled (the default), leaves the traced
 program literally unchanged.  This script records the reference texts
 the assertion suites compare against:
 
-* fused FD (wing + tip): the whole cascade, body = one ``pallas_call``;
 * vmapped FD (wing + tip): the whole Phase 2 as ONE ``while_loop``;
+* per-partition device FD (wing + tip), and the two programs the
+  compacting wing FD driver launches: the chunk loop and the wedge
+  compaction;
 * one-psum pair-aligned CD round (8-device shard_map, subprocess);
 * the multiserve batched dispatch (loop/collective-free).
 
 It was run ONCE at the pre-instrumentation tree to produce
 ``tests/goldens/obs_jaxprs.json``; the suites re-derive the same
 jaxprs from the instrumented tree (telemetry disabled) and assert
-byte-equality (``tests/test_fused_fd.py``, ``tests/test_multiserve.py``,
-``tests/test_core_distributed.py``).  Re-record only when a jaxpr is
-*intentionally* changed on the default path:
+byte-equality (``tests/test_obs.py``, ``tests/test_streaming.py``,
+``tests/test_multiserve.py``, ``tests/test_core_distributed.py``).
+Re-record only when a jaxpr is *intentionally* changed on the default
+path:
 
     PYTHONPATH=src python tests/goldens/record_obs_jaxprs.py
 
@@ -64,8 +67,6 @@ CD_PAIR_ALIGNED_SRC = """
 
 
 def _wing_pack():
-    import numpy as np
-
     from repro.core import csr
     from repro.core.distributed import pack_fd_partitions_csr
     from repro.core.graph import random_bipartite
@@ -75,16 +76,8 @@ def _wing_pack():
     wed = csr.build_wedges(g)
     res = wing_decomposition(g, P=4, engine="csr")
     n_parts = int(res.part.max()) + 1
-    slotted = pack_fd_partitions_csr(
-        wed, res.part, res.support_init, n_parts, bucket=True, slots=True)
-    R, _ = slotted["slot_sizes"]
-    W_rows = np.zeros((n_parts, R), np.int32)
-    w = min(R, slotted["W0"].shape[1])
-    W_rows[:, :w] = slotted["W0"][:, :w]
-    slotted["W_rows"] = W_rows
-    flat = pack_fd_partitions_csr(
+    return pack_fd_partitions_csr(
         wed, res.part, res.support_init, n_parts, bucket=True, flat=True)
-    return slotted, flat
 
 
 def _tip_pack():
@@ -97,39 +90,9 @@ def _tip_pack():
     wed = csr.build_wedges(g)
     res = tip_decomposition(g, side="u", P=4, engine="csr")
     n_parts = int(res.part.max()) + 1
-    stacked = pack_fd_partitions_tip_csr(
-        wed, wed.pair_butterflies0(), res.part, res.support_init,
-        n_parts, bucket=True, stacked=True)
-    bucketed = pack_fd_partitions_tip_csr(
+    return pack_fd_partitions_tip_csr(
         wed, wed.pair_butterflies0(), res.part, res.support_init,
         n_parts, bucket=True)
-    return stacked, bucketed
-
-
-def fused_wing_jaxpr() -> str:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.peel import _fd_wing_fused_impl
-
-    p, _ = _wing_pack()
-    return str(jax.make_jaxpr(_fd_wing_fused_impl)(
-        jnp.asarray(p["slot_e1"]), jnp.asarray(p["slot_e2"]),
-        jnp.asarray(p["slot_valid"]), jnp.asarray(p["W_rows"]),
-        jnp.asarray(p["mine"]), jnp.asarray(p["sup0"]))).strip()
-
-
-def fused_tip_jaxpr() -> str:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.peel import _fd_tip_fused_impl
-
-    p, _ = _tip_pack()
-    return str(jax.make_jaxpr(_fd_tip_fused_impl)(
-        jnp.asarray(p["st_pa"]), jnp.asarray(p["st_pb"]),
-        jnp.asarray(p["st_bf"]), jnp.asarray(p["mine"]),
-        jnp.asarray(p["sup0"]))).strip()
 
 
 def vmapped_wing_jaxpr() -> str:
@@ -138,7 +101,7 @@ def vmapped_wing_jaxpr() -> str:
 
     from repro.core.peel import _fd_wing_vmapped
 
-    _, p = _wing_pack()
+    p = _wing_pack()
     n_pairs = int(p["flat_W0"].shape[0])
     return str(jax.make_jaxpr(
         lambda *a: _fd_wing_vmapped(*a, n_pairs=n_pairs))(
@@ -154,7 +117,7 @@ def vmapped_tip_jaxpr() -> str:
 
     from repro.core.peel import _fd_tip_vmapped
 
-    _, p = _tip_pack()
+    p = _tip_pack()
     return str(jax.make_jaxpr(_fd_tip_vmapped)(
         jnp.asarray(p["pa"]), jnp.asarray(p["pb"]),
         jnp.asarray(p["bf"]), jnp.asarray(p["mine"]),
@@ -200,6 +163,47 @@ def device_tip_jaxpr() -> str:
         mine, sup0, pa, pa, pa)).strip()
 
 
+# Wedge-list length of the compacting-driver cases: twice the floor, so
+# the chunk loop carries a real shrink limit and the compaction halves.
+_COMPACT_SIZE = 8192
+
+
+def wing_chunk_jaxpr() -> str:
+    """One launch of the compacting wing FD driver's chunk loop
+    (``peel._fd_wing_chunk``) on a fixed synthetic shape: the program
+    the chip runs for a wing partition above the compaction floor."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.peel import _fd_wing_chunk
+
+    m, n_pairs = 140, 64
+    zero = jnp.int32(0)
+    state = (jnp.zeros((m,), bool), jnp.zeros((m,), jnp.int32),
+             (jnp.zeros((_COMPACT_SIZE,), bool),
+              jnp.zeros((n_pairs,), jnp.int32)),
+             jnp.zeros((m,), jnp.int32), zero, zero, zero)
+    we = jnp.zeros((_COMPACT_SIZE,), jnp.int32)
+    return str(jax.make_jaxpr(
+        lambda *a: _fd_wing_chunk(*a, n_pairs=n_pairs, m=m))(
+        state, we, we, we)).strip()
+
+
+def compact_wedges_jaxpr() -> str:
+    """The compaction between two chunk launches
+    (``peel._compact_wedges``), halving a fixed synthetic wedge list."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.peel import _compact_wedges
+
+    alive = jnp.zeros((_COMPACT_SIZE,), bool)
+    we = jnp.zeros((_COMPACT_SIZE,), jnp.int32)
+    return str(jax.make_jaxpr(
+        lambda *a: _compact_wedges(*a, size=_COMPACT_SIZE // 2))(
+        alive, we, we, we)).strip()
+
+
 def multiserve_dispatch_jaxpr() -> str:
     """Dispatch jaxpr on a fixed synthetic bucket shape (the program is
     a function of shapes only, so no artifacts are needed)."""
@@ -233,12 +237,12 @@ def cd_pair_aligned_jaxpr() -> str:
 
 
 CASES = {
-    "fused_wing": fused_wing_jaxpr,
-    "fused_tip": fused_tip_jaxpr,
     "vmapped_wing": vmapped_wing_jaxpr,
     "vmapped_tip": vmapped_tip_jaxpr,
     "device_wing": device_wing_jaxpr,
     "device_tip": device_tip_jaxpr,
+    "wing_chunk": wing_chunk_jaxpr,
+    "compact_wedges": compact_wedges_jaxpr,
     "multiserve_dispatch": multiserve_dispatch_jaxpr,
     "cd_pair_aligned_8dev": cd_pair_aligned_jaxpr,
 }

@@ -160,6 +160,14 @@ def test_tip_engines_and_fd_drivers_agree_property(g, P, side):
     assert dev.stats.rho_fd_total == host.stats.rho_fd_total
 
 
+@pytest.mark.parametrize("decompose", [wing_decomposition, tip_decomposition])
+def test_removed_fused_option_is_a_type_error(decompose):
+    """One FD path per driver: the fused option is gone, not ignored."""
+    g = random_bipartite(12, 10, 40, seed=0)
+    with pytest.raises(TypeError, match="fused"):
+        decompose(g, engine="csr", fused=True)
+
+
 # -------------------------------------------------------- scale / guard
 def test_dense_engine_guarded_csr_peels_50k_graph():
     """The acceptance graph: 50k×50k, avg degree 8.

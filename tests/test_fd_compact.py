@@ -90,6 +90,11 @@ GRAPHS = {
     "random-a": lambda: random_bipartite(40, 30, 260, seed=1),
     "random-b": lambda: random_bipartite(60, 25, 400, seed=7),
     "powerlaw": lambda: powerlaw_bipartite(80, 40, 420, seed=2),
+    # the golden graphs of test_peelspec_goldens.py
+    "rb30": lambda: random_bipartite(30, 24, 140, seed=0),
+    "rb25": lambda: random_bipartite(25, 20, 100, seed=1),
+    "pl80": lambda: powerlaw_bipartite(80, 40, 350, seed=2),
+    "pl60": lambda: powerlaw_bipartite(60, 50, 300, seed=3),
 }
 
 

@@ -20,10 +20,10 @@ Four claims under test:
   regression); the snapshot's cache counts match the ``--out`` oracle.
 
 The zero-overhead-off guarantee (byte-identical jaxprs with telemetry
-disabled) is asserted against ``tests/goldens/obs_jaxprs.json`` in
-``test_fused_fd.py`` / ``test_multiserve.py`` /
-``test_core_distributed.py`` next to the structural invariants those
-suites already state.
+disabled) is asserted against ``tests/goldens/obs_jaxprs.json`` here
+for the FD programs, and in ``test_streaming.py`` /
+``test_multiserve.py`` / ``test_core_distributed.py`` next to the
+structural invariants those suites already state.
 """
 import json
 import os
@@ -210,18 +210,18 @@ def test_timeline_ring_truncation():
 # the exact-match oracle: span counts == PeelStats, θ unchanged
 # =====================================================================
 WING_COMBOS = [
-    ("beindex", "device", False),
-    ("beindex", "host", False),
-    ("csr", "device", False),
-    ("csr", "vmapped", False),
-    ("csr", "device", True),             # fused
+    ("beindex", "device"),
+    ("beindex", "host"),
+    ("csr", "device"),
+    ("csr", "vmapped"),
+    ("csr", "host"),
 ]
 TIP_COMBOS = [
-    ("dense", "device", False),
-    ("dense", "host", False),
-    ("csr", "device", False),
-    ("csr", "vmapped", False),
-    ("csr", "device", True),             # fused
+    ("dense", "device"),
+    ("dense", "host"),
+    ("csr", "device"),
+    ("csr", "vmapped"),
+    ("csr", "host"),
 ]
 
 
@@ -251,13 +251,11 @@ def _assert_exact_match(run):
 RING_ENTRIES = [
     (peel_mod, name) for name in (
         "_fd_tip_vmapped_rings", "_fd_wing_vmapped_rings",
-        "_fd_wing_vmapped_pallas_rings", "_fd_wing_fused_rings",
-        "_fd_tip_fused_rings", "_fd_tip_device_rings",
+        "_fd_wing_vmapped_pallas_rings", "_fd_tip_device_rings",
         "_fd_wing_device_rings")
 ] + [
     (peelspec, name) for name in (
-        "_fd_while_device_rings", "_fd_while_vmapped_rings",
-        "_fd_while_fused_rings")
+        "_fd_while_device_rings", "_fd_while_vmapped_rings")
 ]
 
 
@@ -365,15 +363,14 @@ def _modes(combos):
                for c in combos])
 
 
-@pytest.mark.parametrize("engine,fd_driver,fused,timeline",
-                         _modes(WING_COMBOS))
-def test_wing_trace_counts_match_stats(engine, fd_driver, fused, timeline,
+@pytest.mark.parametrize("engine,fd_driver,timeline", _modes(WING_COMBOS))
+def test_wing_trace_counts_match_stats(engine, fd_driver, timeline,
                                        monkeypatch):
     g = random_bipartite(30, 24, 140, seed=1)
 
     def run():
         return wing_decomposition(g, P=4, engine=engine,
-                                  fd_driver=fd_driver, fused=fused)
+                                  fd_driver=fd_driver)
 
     if timeline:
         _assert_exact_match(run)
@@ -382,15 +379,14 @@ def test_wing_trace_counts_match_stats(engine, fd_driver, fused, timeline,
                            fd_driver, monkeypatch)
 
 
-@pytest.mark.parametrize("engine,fd_driver,fused,timeline",
-                         _modes(TIP_COMBOS))
-def test_tip_trace_counts_match_stats(engine, fd_driver, fused, timeline,
+@pytest.mark.parametrize("engine,fd_driver,timeline", _modes(TIP_COMBOS))
+def test_tip_trace_counts_match_stats(engine, fd_driver, timeline,
                                       monkeypatch):
     g = random_bipartite(30, 24, 140, seed=1)
 
     def run():
         return tip_decomposition(g, side="u", P=4, engine=engine,
-                                 fd_driver=fd_driver, fused=fused)
+                                 fd_driver=fd_driver)
 
     if timeline:
         _assert_exact_match(run)
@@ -398,6 +394,22 @@ def test_tip_trace_counts_match_stats(engine, fd_driver, fused, timeline,
         _assert_spans_only(
             run, lambda res: build_hierarchy(g, res, kind="tip", side="u"),
             fd_driver, monkeypatch)
+
+
+@pytest.mark.parametrize("case", ["vmapped_wing", "vmapped_tip",
+                                  "wing_chunk", "compact_wedges"])
+def test_obs_off_fd_jaxprs_byte_identical(obs_golden, case):
+    """Zero-overhead-off: with telemetry disabled (the default), each FD
+    program re-derived from the instrumented tree is byte-identical to
+    its golden (``tests/goldens/obs_jaxprs.json``).  The counter rings
+    live in separate ``*_rings`` jit twins, so the default entries may
+    not trace a single extra op.  ``wing_chunk`` and ``compact_wedges``
+    are the two programs the compacting wing FD driver launches."""
+    rec, golden = obs_golden
+    assert not obs.enabled()
+    assert rec.CASES[case]() == golden[case], \
+        f"{case}: default-path jaxpr drifted from the telemetry-off " \
+        f"golden (re-record ONLY for intentional program changes)"
 
 
 def test_distributed_trace_counts_match_stats():

@@ -1,4 +1,4 @@
-"""Compile the main path's kernels and the unfused csr FD program for a
+"""Compile the main path's kernels and the csr device FD program for a
 described TPU v5e, at the widths of ``chip_smoke.py``'s phase-(b) graph
 (``tests/goldens/record_chip_smoke.py``'s ``peel`` graph: |E| =
 200,000, 16,483,275 V-centred wedges in 15,423,111 pairs).
@@ -102,7 +102,7 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 def test_wing_fd_while_compiles_for_v5e(one_chip):
-    """The unfused device FD program of the largest bucket a phase-(b)
+    """The device FD program of the largest bucket a phase-(b)
     wing partition can take (the whole wedge list)."""
     s = one_chip
     hlo = _compile(_fd_wing_device.lower(
